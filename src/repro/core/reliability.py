@@ -223,11 +223,6 @@ class ReliableStore:
 # the baseline that benchmarks/kernels_bench.py measures the arena against.
 # --------------------------------------------------------------------------
 
-def _leaf_spec(x: jax.Array, n_words: int) -> arena.LeafSpec:
-    return arena.LeafSpec(offset=0, n_words=n_words, pad_words=0,
-                          dtype=x.dtype, shape=tuple(x.shape))
-
-
 def _pad_leaf_words(x: jax.Array) -> jax.Array:
     words = arena.leaf_to_words(x)
     pad = (-words.shape[0]) % BLOCK
@@ -249,8 +244,8 @@ def scrub_leaves(params: Any, parity_tree: Any,
     for x, par in zip(leaves, pleaves):
         words = _pad_leaf_words(x)
         fixed, par2, rep = correct_words(words, par, cfg)
-        n_words = arena._words_per_leaf(x)
-        out_p.append(arena.words_to_leaf(fixed[:n_words], _leaf_spec(x, n_words)))
+        spec = arena.leaf_spec(x)
+        out_p.append(arena.words_to_leaf(fixed[:spec.n_words], spec))
         out_c.append(par2)
         reps.append(rep)
     total = ScrubReport(

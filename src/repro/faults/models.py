@@ -27,18 +27,20 @@ property of the device, not of the interval).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.random import threefry2x32_p
 
 from ..core import arena
 from ..core.bitops import PACK, pack_trials
 
 __all__ = ["FaultModel", "TransientBitFlips", "TransientGateFaults",
            "StuckAtFaults", "RetentionDrift", "CompositeFault",
-           "inject_bit_flips", "pack_flip_mask"]
+           "inject_bit_flips", "pack_flip_mask", "corrupt_leaf", "uniform"]
 
 BLOCK = arena.BLOCK
 
@@ -50,6 +52,48 @@ def _p_interval(p: float, dt: float) -> float:
     if p >= 1.0:
         return 1.0
     return -math.expm1(dt * math.log1p(-p))
+
+
+def uniform(key: jax.Array, shape: Tuple[int, ...],
+            offset: Optional[jax.Array] = None) -> jax.Array:
+    """`jax.random.uniform(key, shape)` — or, with `offset`, rows
+    ``[offset, offset + shape[0])`` of ``jax.random.uniform(key, (n,) +
+    shape[1:])`` for any n, bit for bit.
+
+    Threefry's counter-based bits depend only on an element's flat index,
+    so rows of one large draw can be made slab by slab: a big leaf is
+    corrupted a slab at a time with exactly the draws of one whole-leaf
+    call, and no whole-leaf temporary is ever built.  Rows must hold a
+    power-of-two number of elements (a word mask's rows hold 32 bits)."""
+    if offset is None:
+        return jax.random.uniform(key, shape)
+    assert jax.config.jax_threefry_partitionable, \
+        "row-offset draws need the counter-based threefry bits"
+    row = math.prod(shape[1:])
+    shift = row.bit_length() - 1
+    assert row == 1 << shift, shape
+    if jax.dtypes.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    r = jnp.asarray(offset, jnp.uint32) \
+        + jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
+    within = jnp.zeros(shape, jnp.uint32)
+    stride = 1
+    for d in range(len(shape) - 1, 0, -1):
+        within = within + jax.lax.broadcasted_iota(jnp.uint32, shape, d) \
+            * jnp.uint32(stride)
+        stride *= shape[d]
+    # 64-bit flat index r * row + within, as the (hi, lo) counter pair
+    hi = r >> (32 - shift) if shift else jnp.zeros(shape, jnp.uint32)
+    lo = (r << shift) | within
+    b1, b2 = threefry2x32_p.bind(key[0], key[1], hi, lo)
+    mant = ((b1 ^ b2) >> 9) | jnp.uint32(0x3F800000)     # as in uniform
+    return jax.lax.bitcast_convert_type(mant, jnp.float32) - 1.0
+
+
+def _bernoulli(key, p: float, shape, offset=None) -> jax.Array:
+    if offset is None:
+        return jax.random.bernoulli(key, p, shape)
+    return uniform(key, shape, offset) < jnp.float32(p)
 
 
 def pack_flip_mask(flips: jax.Array) -> jax.Array:
@@ -73,8 +117,9 @@ class FaultModel:
 
     # -- boolean-state surface ------------------------------------------------
     def bit_flips(self, key: jax.Array, shape: Tuple[int, ...],
-                  dt: float = 1.0) -> jax.Array:
-        """Bool XOR plane: True where a stored bit flips during dt."""
+                  dt: float = 1.0, offset=None) -> jax.Array:
+        """Bool XOR plane: True where a stored bit flips during dt.  With
+        `offset`, rows offset.. of a larger plane (see `uniform`)."""
         raise NotImplementedError(
             f"{type(self).__name__} is data-dependent; use corrupt_bits")
 
@@ -84,13 +129,16 @@ class FaultModel:
 
     # -- packed-word surface (ECC arena) --------------------------------------
     def word_mask(self, key: jax.Array, words: jax.Array,
-                  dt: float = 1.0) -> jax.Array:
-        """uint32 XOR mask over `words` (may inspect the data for stuck-at)."""
-        return pack_flip_mask(self.bit_flips(key, words.shape + (BLOCK,), dt))
+                  dt: float = 1.0, offset=None) -> jax.Array:
+        """uint32 XOR mask over `words` (may inspect the data for stuck-at).
+        With `offset`, `words` are words offset.. of a longer buffer and
+        the mask is that buffer's mask over them."""
+        return pack_flip_mask(self.bit_flips(key, words.shape + (BLOCK,), dt,
+                                             offset))
 
     def corrupt_words(self, words: jax.Array, key: jax.Array,
-                      dt: float = 1.0) -> jax.Array:
-        return words ^ self.word_mask(key, words, dt)
+                      dt: float = 1.0, offset=None) -> jax.Array:
+        return words ^ self.word_mask(key, words, dt, offset)
 
     # -- packed-trial surface (netlist execution engines) ----------------------
     def gate_lane_masks(self, key: jax.Array, trials: int,
@@ -114,15 +162,21 @@ class FaultModel:
         """Corrupt every leaf's stored bits (via the arena word view)."""
         leaves, treedef = jax.tree.flatten(params)
         keys = jax.random.split(key, len(leaves))
-        out = []
-        for x, k in zip(leaves, keys):
-            words = arena.leaf_to_words(x)
-            spec = arena.LeafSpec(offset=0, n_words=words.shape[0],
-                                  pad_words=0, dtype=x.dtype,
-                                  shape=tuple(x.shape))
-            out.append(arena.words_to_leaf(
-                self.corrupt_words(words, k, dt), spec))
-        return treedef.unflatten(out)
+        return treedef.unflatten([corrupt_leaf(x, k, self, dt)
+                                  for x, k in zip(leaves, keys)])
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def corrupt_leaf(x: jax.Array, key: jax.Array, model: FaultModel,
+                 dt: float = 1.0) -> jax.Array:
+    """One leaf's stored bits through its arena word view, as one compiled
+    program that walks the leaf in slabs (`arena.map_leaf_words`): the
+    (words, 32) flip plane is packed into the XOR mask slab by slab and
+    never reaches device memory.  The draws are those of
+    `model.corrupt_words` on the whole leaf's words."""
+    def corrupt(words, offset):
+        return model.corrupt_words(words, key, dt, offset=offset), ()
+    return arena.map_leaf_words(x, corrupt)[0]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,8 +186,8 @@ class TransientBitFlips(FaultModel):
 
     p_bit: float = 0.0
 
-    def bit_flips(self, key, shape, dt: float = 1.0):
-        return jax.random.bernoulli(key, _p_interval(self.p_bit, dt), shape)
+    def bit_flips(self, key, shape, dt: float = 1.0, offset=None):
+        return _bernoulli(key, _p_interval(self.p_bit, dt), shape, offset)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,8 +197,8 @@ class TransientGateFaults(FaultModel):
 
     p_gate: float = 0.0
 
-    def bit_flips(self, key, shape, dt: float = 1.0):
-        return jax.random.bernoulli(key, _p_interval(self.p_gate, dt), shape)
+    def bit_flips(self, key, shape, dt: float = 1.0, offset=None):
+        return _bernoulli(key, _p_interval(self.p_gate, dt), shape, offset)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -161,9 +215,10 @@ class StuckAtFaults(FaultModel):
     def permanent(self) -> bool:
         return True
 
-    def stuck_masks(self, key: jax.Array, shape: Tuple[int, ...]):
+    def stuck_masks(self, key: jax.Array, shape: Tuple[int, ...],
+                    offset=None):
         """(sa0, sa1) bool defect maps; disjoint by construction."""
-        u = jax.random.uniform(key, shape)
+        u = uniform(key, shape, offset)
         sa0 = u < self.p_stuck0
         sa1 = (u >= self.p_stuck0) & (u < self.p_stuck0 + self.p_stuck1)
         return sa0, sa1
@@ -172,8 +227,8 @@ class StuckAtFaults(FaultModel):
         sa0, sa1 = self.stuck_masks(key, bits.shape)
         return (bits & ~sa0) | sa1
 
-    def word_mask(self, key, words, dt: float = 1.0):
-        sa0, sa1 = self.stuck_masks(key, words.shape + (BLOCK,))
+    def word_mask(self, key, words, dt: float = 1.0, offset=None):
+        sa0, sa1 = self.stuck_masks(key, words.shape + (BLOCK,), offset)
         sa0w, sa1w = pack_flip_mask(sa0), pack_flip_mask(sa1)
         return (words & sa0w) | (~words & sa1w)
 
@@ -192,8 +247,8 @@ class RetentionDrift(FaultModel):
 
     p_unit: float = 0.0
 
-    def bit_flips(self, key, shape, dt: float = 1.0):
-        return jax.random.bernoulli(key, _p_interval(self.p_unit, dt), shape)
+    def bit_flips(self, key, shape, dt: float = 1.0, offset=None):
+        return _bernoulli(key, _p_interval(self.p_unit, dt), shape, offset)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -212,13 +267,13 @@ class CompositeFault(FaultModel):
             bits = m.corrupt_bits(bits, k, dt)
         return bits
 
-    def corrupt_words(self, words, key, dt: float = 1.0):
+    def corrupt_words(self, words, key, dt: float = 1.0, offset=None):
         for m, k in zip(self.models, jax.random.split(key, len(self.models))):
-            words = m.corrupt_words(words, k, dt)
+            words = m.corrupt_words(words, k, dt, offset)
         return words
 
-    def word_mask(self, key, words, dt: float = 1.0):
-        return self.corrupt_words(words, key, dt) ^ words
+    def word_mask(self, key, words, dt: float = 1.0, offset=None):
+        return self.corrupt_words(words, key, dt, offset) ^ words
 
     def gate_lane_masks(self, key, trials: int, dt: float = 1.0):
         # lanewise affine composition: f2(f1(v)) with f = (v & K) ^ F gives

@@ -5,10 +5,12 @@ Each kernel package has:
   ops.py    — jit'd public wrapper (handles layout/padding, interpret flag)
   ref.py    — pure-jnp oracle used by the allclose test sweeps
 
-This container is CPU-only: kernels are validated with interpret=True
-(the kernel body executes on CPU); on a real TPU set
-REPRO_PALLAS_INTERPRET=0.  The flag is owned by the backend registry
+Interpret mode follows the platform: on the CPU backend (where the tests
+run) the kernel bodies execute in the Pallas interpreter, and on a TPU they
+compile to Mosaic.  The choice is owned by the backend registry
 (reliability/backend.py, DESIGN.md §12); `use_interpret` here is a shim.
+The `*_kernel` entry points default to compiled (`interpret=False`); the
+ops wrappers pass `use_interpret()`.
 
 Kernels:
   diag_parity     — rotate-XOR diagonal-parity encode (ECC hot loop, §IV)

@@ -26,11 +26,11 @@ def _kernel(gates_ref, state_in_ref, state_ref, *, n_gates: int):
 
     def body(g, carry):
         row = gates_ref[g]                     # (4,) int32: in1, in2, in3, out
-        a = pl.load(state_ref, (slice(None), pl.dslice(row[0], 1)))
-        b = pl.load(state_ref, (slice(None), pl.dslice(row[1], 1)))
-        c = pl.load(state_ref, (slice(None), pl.dslice(row[2], 1)))
+        a = state_ref[:, pl.ds(row[0], 1)]
+        b = state_ref[:, pl.ds(row[1], 1)]
+        c = state_ref[:, pl.ds(row[2], 1)]
         maj = (a & b) | (b & c) | (a & c)
-        pl.store(state_ref, (slice(None), pl.dslice(row[3], 1)), ~maj)
+        state_ref[:, pl.ds(row[3], 1)] = ~maj
         return carry
 
     jax.lax.fori_loop(0, n_gates, body, 0)
@@ -38,7 +38,7 @@ def _kernel(gates_ref, state_in_ref, state_ref, *, n_gates: int):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def netlist_kernel(gates: jax.Array, state: jax.Array,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool = False) -> jax.Array:
     """gates: (G, 4) int32 Min3 netlist; state: (tw, n_wires) uint32 packed
     trials.  Returns the final wire state."""
     G = gates.shape[0]

@@ -3,8 +3,9 @@
 `encode_parity` is the protect/refresh path; `scrub` is the fused
 encode->syndrome->locate->correct pass.  Both take a flat uint32 buffer
 (the packed arena of core/arena.py) so the whole parameter pytree is one
-launch.  Padding blocks are zero words with zero parity — their syndrome
-is identically clean, so they never contribute to the stats.
+launch, laid out for the kernels by kernels/arena_tiles.py.  Padding
+blocks are zero words with zero parity — their syndrome is identically
+clean, so they never contribute to the stats.
 """
 from __future__ import annotations
 
@@ -13,30 +14,29 @@ from typing import Callable, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import arena_tiles as tiles
 from .. import use_interpret
 from .kernel import BLOCK, encode_parity_kernel, scrub_kernel
 
 
 def encode_parity(buf: jax.Array, slopes: Tuple[int, ...] = (1, 2, -1),
-                  block_m: int = 256, interpret: bool | None = None) -> jax.Array:
+                  block_rows: int = 512,
+                  interpret: bool | None = None) -> jax.Array:
     """buf: flat uint32 buffer (length multiple of 32) ->
     (n_blocks, len(slopes)) parity words."""
     assert buf.ndim == 1 and buf.shape[0] % BLOCK == 0
-    words = buf.reshape(-1, BLOCK)
-    n = words.shape[0]
+    n = buf.shape[0] // BLOCK
     if n == 0:
         return jnp.zeros((0, len(slopes)), jnp.uint32)
-    bm = min(block_m, n)
-    pad = (-n) % bm if n > bm else 0
-    if pad:
-        words = jnp.pad(words, ((0, pad), (0, 0)))
-    out = encode_parity_kernel(words, slopes=tuple(slopes), block_m=bm,
-                               interpret=use_interpret() if interpret is None else interpret)
-    return out[:n]
+    rows, nb = tiles.plan(n, block_rows)
+    out = encode_parity_kernel(
+        tiles.words_view(buf, nb), slopes=tuple(slopes), rows=rows,
+        interpret=use_interpret() if interpret is None else interpret)
+    return tiles.parity_from_kernel(out, n)
 
 
 def scrub(buf: jax.Array, parity: jax.Array,
-          slopes: Tuple[int, ...] = (1, 2, -1), block_m: int = 256,
+          slopes: Tuple[int, ...] = (1, 2, -1), block_rows: int = 512,
           interpret: bool | None = None):
     """Fused scrub of a flat uint32 buffer against its parity table.
 
@@ -45,23 +45,21 @@ def scrub(buf: jax.Array, parity: jax.Array,
     int32 vector: corrected, parity_fixed, uncorrectable.
     """
     assert buf.ndim == 1 and buf.shape[0] % BLOCK == 0
-    words = buf.reshape(-1, BLOCK)
-    n = words.shape[0]
+    n = buf.shape[0] // BLOCK
     assert parity.shape == (n, len(slopes)), (parity.shape, n)
     if n == 0:
         return buf, parity, jnp.zeros((3,), jnp.int32)
-    pad = (-n) % block_m if n > block_m else 0
-    if pad:
-        words = jnp.pad(words, ((0, pad), (0, 0)))
-        parity = jnp.pad(parity, ((0, pad), (0, 0)))
+    rows, nb = tiles.plan(n, block_rows)
     fixed, par2, stats = scrub_kernel(
-        words, parity, slopes=tuple(slopes), block_m=block_m,
+        tiles.words_view(buf, nb), tiles.parity_to_kernel(parity, nb),
+        slopes=tuple(slopes), rows=rows,
         interpret=use_interpret() if interpret is None else interpret)
-    return fixed[:n].reshape(-1), par2[:n], stats.sum(axis=0)
+    return (tiles.flat(fixed, n), tiles.parity_from_kernel(par2, n),
+            stats[0, :3])
 
 
 def scrub_sharded(buf: jax.Array, parity: jax.Array,
-                  slopes: Tuple[int, ...] = (1, 2, -1), block_m: int = 256,
+                  slopes: Tuple[int, ...] = (1, 2, -1), block_rows: int = 512,
                   interpret: bool | None = None, *, mesh=None,
                   axes: Sequence[str] = ("copy", "data", "model"),
                   local_scrub: Optional[Callable] = None):
@@ -72,7 +70,7 @@ def scrub_sharded(buf: jax.Array, parity: jax.Array,
     overrides the per-shard op (backend registry passes the jnp oracle)."""
     if local_scrub is None:
         def local_scrub(b, p):
-            return scrub(b, p, slopes=tuple(slopes), block_m=block_m,
+            return scrub(b, p, slopes=tuple(slopes), block_rows=block_rows,
                          interpret=interpret)
     if mesh is None:
         return local_scrub(buf, parity)

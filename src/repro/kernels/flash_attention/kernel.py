@@ -82,7 +82,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "block_q", "block_k", "interpret"))
 def flash_attention_kernel(q, k, v, *, causal=True, window=0,
-                           block_q=512, block_k=512, interpret=True):
+                           block_q=512, block_k=512, interpret=False):
     """q: (B, H, Sq, hd); k/v: (B, KV, Sk, hd) -> (B, H, Sq, hd)."""
     B, H, Sq, hd = q.shape
     KV, Sk = k.shape[1], k.shape[2]

@@ -1,18 +1,21 @@
 """Hsiao SEC-DED kernels: fused encode → syndrome → classify → correct.
 
-Same tiling family as kernels/diag_parity: (n_blocks, 32) uint32 word
-tiles with `bm` blocks per grid step, parity tiles (bm, 7).  The encode
-is 7 masked-popcount parities per word, packed over the 32 words of a
-block into one uint32 per check bit; the scrub recomputes them, XORs
-against the stored table, reassembles a per-word 7-bit syndrome and
-classifies it against the 39 compile-time column constants — 32 data
-columns and 7 unit vectors — with unrolled equality compares, so the
-whole decode is branch- and gather-free on the VPU.
+Same tiling as kernels/diag_parity (kernels/arena_tiles.py): each tile is
+transposed in VMEM so a block's 32 words run down the sublanes and blocks
+sit across the lanes, with (7, blocks) parity tiles.  The encode is 7
+masked-popcount parities per word, packed over the 32 words of a block
+into one uint32 per check bit; the scrub recomputes them, XORs against
+the stored table, reassembles a per-word 7-bit syndrome and classifies it
+against the 39 compile-time column constants — 32 data columns and 7 unit
+vectors — with unrolled equality compares, so the whole decode is branch-
+and gather-free on the VPU.  Bit packing sums disjoint powers of two in
+int32 (the TPU has no unsigned reductions), which equals the OR of the
+bits modulo 2^32.
 
 Unlike the diagonal code (one correction per block), every word of a
-block decodes independently: the per-tile stats count words, not
-blocks.  A syndrome that is nonzero but matches no column is a detected
-double error; the word is left untouched and reported uncorrectable.
+block decodes independently: the stats count words, not blocks.  A
+syndrome that is nonzero but matches no column is a detected double
+error; the word is left untouched and reported uncorrectable.
 """
 from __future__ import annotations
 
@@ -23,57 +26,75 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...core.bitops import popcount32
+from .. import arena_tiles as tiles
 from .code import CHECK_MASKS, DATA_COLUMNS, N_CHECKS
 
-BLOCK = 32
+BLOCK = tiles.BLOCK
+
+
+def _pack_rows(bits: jax.Array) -> jax.Array:
+    """(32, n) uint32 holding disjoint bits down each column -> (1, n)
+    uint32, their OR, reduced as an int32 sum."""
+    total = jax.lax.bitcast_convert_type(bits, jnp.int32).sum(
+        axis=0, keepdims=True)
+    return jax.lax.bitcast_convert_type(total, jnp.uint32)
+
+
+def _word_index(w: jax.Array) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.uint32, w.shape, 0)
 
 
 def _encode_checks(w: jax.Array) -> list:
-    """w (bm, 32) uint32 -> 7 packed check words, each (bm,) uint32 with
-    check bit j of word i at bit position i."""
-    lane = jax.lax.broadcasted_iota(jnp.uint32, w.shape, 1)
+    """w (32, n) uint32 (word i in row i) -> 7 packed check words, each
+    (1, n) uint32 with check bit j of word i at bit position i."""
+    word = _word_index(w)
     out = []
     for m in CHECK_MASKS:
         bit = (popcount32(w & jnp.uint32(m)) & 1).astype(jnp.uint32)
-        out.append((bit << lane).sum(axis=-1, dtype=jnp.uint32))
+        out.append(_pack_rows(bit << word))
     return out
 
 
-def _encode_kernel(words_ref, out_ref):
-    out_ref[...] = jnp.stack(_encode_checks(words_ref[...]), axis=-1)
+def _encode_kernel(words_ref, out_ref, scratch):
+    out_ref[...] = tiles.join_parity(
+        [jnp.concatenate(_encode_checks(w), axis=0)
+         for w in tiles.tile_blocks(words_ref[...])], scratch)
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
-def encode_hsiao_kernel(words: jax.Array, block_m: int = 256,
-                        interpret: bool = True) -> jax.Array:
-    """words: (n_blocks, 32) uint32 -> parity (n_blocks, 7) uint32."""
-    n_blocks = words.shape[0]
-    bm = min(block_m, n_blocks)
-    assert n_blocks % bm == 0, (n_blocks, bm)
+@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
+def encode_hsiao_kernel(words: jax.Array, rows: int = 512,
+                        interpret: bool = False) -> jax.Array:
+    """words: (n_blocks / 4, 128) uint32 (`arena_tiles.words_view`) ->
+    parity (7, n_blocks) uint32."""
+    n_rows = words.shape[0]
+    assert n_rows % rows == 0, (n_rows, rows)
     return pl.pallas_call(
         _encode_kernel,
-        grid=(n_blocks // bm,),
-        in_specs=[pl.BlockSpec((bm, BLOCK), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bm, N_CHECKS), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks, N_CHECKS), jnp.uint32),
+        grid=(n_rows // rows,),
+        in_specs=[tiles.words_spec(rows)],
+        out_specs=tiles.parity_spec(N_CHECKS, rows),
+        out_shape=jax.ShapeDtypeStruct((N_CHECKS, n_rows * tiles.SEGS),
+                                       jnp.uint32),
+        scratch_shapes=[tiles.parity_scratch(rows)],
         interpret=interpret,
     )(words)
 
 
 def hsiao_body(w: jax.Array, p: jax.Array):
-    """The fused tile body: w (bm, 32) data words, p (bm, 7) parity words.
+    """The fused body: w (32, n) data words of n blocks (word i in row i),
+    p (7, n) parity words.
 
     Returns (corrected w, corrected p, data_err, check_err, uncorrectable)
-    with the last three bool (bm, 32) per-WORD classifications.
+    with the last three bool (32, n) per-WORD classifications.
     """
-    lane = jax.lax.broadcasted_iota(jnp.uint32, w.shape, 1)
+    word = _word_index(w)
     enc = _encode_checks(w)
 
-    # per-word syndrome: bit j of s[b, i] = bit i of (enc_j ^ p[:, j])
+    # per-word syndrome: bit j of s[i, b] = bit i of (enc_j ^ p[j])
     s = jnp.zeros_like(w)
     for j in range(N_CHECKS):
-        syn_j = enc[j] ^ p[:, j]                     # (bm,) packed over i
-        s = s | ((((syn_j[:, None] >> lane) & jnp.uint32(1))) << jnp.uint32(j))
+        syn_j = enc[j] ^ p[j:j + 1]                  # (1, n) packed over i
+        s = s | (((syn_j >> word) & jnp.uint32(1)) << jnp.uint32(j))
 
     # classify against the 39 compile-time columns (unrolled compares)
     data_err = jnp.zeros(w.shape, jnp.bool_)
@@ -88,48 +109,44 @@ def hsiao_body(w: jax.Array, p: jax.Array):
     for j in range(N_CHECKS):
         eq = s == jnp.uint32(1 << j)                 # check bit j flipped
         check_err |= eq
-        out_p.append(p[:, j] ^ (eq.astype(jnp.uint32) << lane)
-                     .sum(axis=-1, dtype=jnp.uint32))
+        out_p.append(p[j:j + 1] ^ _pack_rows(eq.astype(jnp.uint32) << word))
     uncorrectable = (s != 0) & ~data_err & ~check_err
 
-    return (w ^ flip, jnp.stack(out_p, axis=-1),
+    return (w ^ flip, jnp.concatenate(out_p, axis=0),
             data_err, check_err, uncorrectable)
 
 
-def _scrub_kernel(words_ref, parity_ref, out_w_ref, out_p_ref, stats_ref):
-    out_w, out_p, data_err, check_err, uncorr = hsiao_body(
-        words_ref[...], parity_ref[...])
+def _scrub_kernel(words_ref, parity_ref, out_w_ref, out_p_ref, stats_ref,
+                  scratch):
+    out_w, out_p, counts = tiles.scrub_planes(
+        words_ref[...], parity_ref[...], hsiao_body, scratch)
     out_w_ref[...] = out_w
     out_p_ref[...] = out_p
-    stats_ref[...] = jnp.stack([
-        data_err.astype(jnp.int32).sum(),
-        check_err.astype(jnp.int32).sum(),
-        uncorr.astype(jnp.int32).sum(),
-    ]).reshape(1, 3)
+    tiles.accumulate_stats(stats_ref, counts)
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
+@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
 def scrub_hsiao_kernel(words: jax.Array, parity: jax.Array,
-                       block_m: int = 256, interpret: bool = True):
-    """Fused scrub: words (n_blocks, 32) + parity (n_blocks, 7) uint32 ->
-    (corrected words, corrected parity, per-tile stats (grid, 3) int32).
+                       rows: int = 512, interpret: bool = False):
+    """Fused scrub: words (n_blocks / 4, 128) + parity (7, n_blocks) uint32
+    in the `arena_tiles` layout -> (corrected words, corrected parity,
+    stats tile (8, 128) int32).
 
-    stats columns: corrected, parity_fixed, uncorrectable — per word.
+    stats row 0, lanes 0..2: corrected, parity_fixed, uncorrectable — per
+    word, summed over the grid.
     """
-    n_blocks = words.shape[0]
-    bm = min(block_m, n_blocks)
-    assert n_blocks % bm == 0, (n_blocks, bm)
-    grid = n_blocks // bm
+    n_rows = words.shape[0]
+    assert n_rows % rows == 0, (n_rows, rows)
     return pl.pallas_call(
         _scrub_kernel,
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((bm, BLOCK), lambda i: (i, 0)),
-                  pl.BlockSpec((bm, N_CHECKS), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((bm, BLOCK), lambda i: (i, 0)),
-                   pl.BlockSpec((bm, N_CHECKS), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 3), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.uint32),
-                   jax.ShapeDtypeStruct((n_blocks, N_CHECKS), jnp.uint32),
-                   jax.ShapeDtypeStruct((grid, 3), jnp.int32)],
+        grid=(n_rows // rows,),
+        in_specs=[tiles.words_spec(rows), tiles.parity_spec(N_CHECKS, rows)],
+        out_specs=[tiles.words_spec(rows), tiles.parity_spec(N_CHECKS, rows),
+                   tiles.STATS_SPEC],
+        out_shape=[jax.ShapeDtypeStruct(words.shape, jnp.uint32),
+                   jax.ShapeDtypeStruct(parity.shape, jnp.uint32),
+                   jax.ShapeDtypeStruct(tiles.STATS_SHAPE, jnp.int32)],
+        scratch_shapes=[tiles.parity_scratch(rows)],
+        compiler_params=tiles.SEQUENTIAL,
         interpret=interpret,
     )(words, parity)

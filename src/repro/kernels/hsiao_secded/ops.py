@@ -12,31 +12,28 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from .. import arena_tiles as tiles
 from .. import use_interpret
 from .code import N_CHECKS
 from .kernel import BLOCK, encode_hsiao_kernel, scrub_hsiao_kernel
 
 
-def encode_hsiao(buf: jax.Array, block_m: int = 256,
+def encode_hsiao(buf: jax.Array, block_rows: int = 512,
                  interpret: bool | None = None) -> jax.Array:
     """buf: flat uint32 buffer (length multiple of 32) ->
     (n_blocks, 7) parity words."""
     assert buf.ndim == 1 and buf.shape[0] % BLOCK == 0
-    words = buf.reshape(-1, BLOCK)
-    n = words.shape[0]
+    n = buf.shape[0] // BLOCK
     if n == 0:
         return jnp.zeros((0, N_CHECKS), jnp.uint32)
-    bm = min(block_m, n)
-    pad = (-n) % bm if n > bm else 0
-    if pad:
-        words = jnp.pad(words, ((0, pad), (0, 0)))
+    rows, nb = tiles.plan(n, block_rows)
     out = encode_hsiao_kernel(
-        words, block_m=bm,
+        tiles.words_view(buf, nb), rows=rows,
         interpret=use_interpret() if interpret is None else interpret)
-    return out[:n]
+    return tiles.parity_from_kernel(out, n)
 
 
-def scrub(buf: jax.Array, parity: jax.Array, block_m: int = 256,
+def scrub(buf: jax.Array, parity: jax.Array, block_rows: int = 512,
           interpret: bool | None = None):
     """Fused scrub of a flat uint32 buffer against its Hsiao table.
 
@@ -45,22 +42,20 @@ def scrub(buf: jax.Array, parity: jax.Array, block_m: int = 256,
     int32 vector: corrected, parity_fixed, uncorrectable — per word.
     """
     assert buf.ndim == 1 and buf.shape[0] % BLOCK == 0
-    words = buf.reshape(-1, BLOCK)
-    n = words.shape[0]
+    n = buf.shape[0] // BLOCK
     assert parity.shape == (n, N_CHECKS), (parity.shape, n)
     if n == 0:
         return buf, parity, jnp.zeros((3,), jnp.int32)
-    pad = (-n) % block_m if n > block_m else 0
-    if pad:
-        words = jnp.pad(words, ((0, pad), (0, 0)))
-        parity = jnp.pad(parity, ((0, pad), (0, 0)))
+    rows, nb = tiles.plan(n, block_rows)
     fixed, par2, stats = scrub_hsiao_kernel(
-        words, parity, block_m=block_m,
+        tiles.words_view(buf, nb), tiles.parity_to_kernel(parity, nb),
+        rows=rows,
         interpret=use_interpret() if interpret is None else interpret)
-    return fixed[:n].reshape(-1), par2[:n], stats.sum(axis=0)
+    return (tiles.flat(fixed, n), tiles.parity_from_kernel(par2, n),
+            stats[0, :3])
 
 
-def scrub_sharded(buf: jax.Array, parity: jax.Array, block_m: int = 256,
+def scrub_sharded(buf: jax.Array, parity: jax.Array, block_rows: int = 512,
                   interpret: bool | None = None, *, mesh=None,
                   axes: Sequence[str] = ("copy", "data", "model"),
                   local_scrub: Optional[Callable] = None):
@@ -69,7 +64,7 @@ def scrub_sharded(buf: jax.Array, parity: jax.Array, block_m: int = 256,
     compose exactly.  With mesh=None this IS `scrub`."""
     if local_scrub is None:
         def local_scrub(b, p):
-            return scrub(b, p, block_m=block_m, interpret=interpret)
+            return scrub(b, p, block_rows=block_rows, interpret=interpret)
     if mesh is None:
         return local_scrub(buf, parity)
     from ..sharded import shard_scrub

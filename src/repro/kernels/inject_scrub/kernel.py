@@ -4,7 +4,7 @@ One Pallas launch executes a whole trial interval over the packed arena:
 
     inject (XOR the fault mask) → encode → syndrome → locate → correct
 
-The tile body is diag_parity's shared `scrub_body` (DESIGN.md §9) with the
+The tile body is diag_parity's shared `scrub_tile` (DESIGN.md §9) with the
 corruption folded in front of the XOR trees: the corrupted words exist only
 in VMEM — they are never round-tripped through HBM between injection and
 scrub, which is exactly the memory traffic a campaign of thousands of
@@ -12,7 +12,7 @@ trials cares about.  The fault mask is sampled *outside* the kernel by a
 faults.models.FaultModel (threefry — deterministic and identical to the jnp
 oracle), so the kernel stays bit-exact testable against ref.py.
 
-Per-tile stats gain a 4th counter, `injected` (popcount of the mask), so a
+The stats tile gains a 4th counter, `injected` (popcount of the mask), so a
 campaign reads (injected, corrected, parity_fixed, uncorrectable) for the
 batch from one launch.
 """
@@ -26,51 +26,47 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...core.bitops import popcount32
-from ..diag_parity.kernel import BLOCK, scrub_body
+from .. import arena_tiles as tiles
+from ..diag_parity.kernel import scrub_tile
 
 
 def _inject_scrub_kernel(words_ref, parity_ref, mask_ref,
-                         out_w_ref, out_p_ref, stats_ref,
+                         out_w_ref, out_p_ref, stats_ref, scratch,
                          *, slopes: Tuple[int, ...]):
-    w = words_ref[...] ^ mask_ref[...]      # (bm, 32) uint32 — the injection
-    out_w, out_p, data_err, parity_err, uncorrectable = scrub_body(
-        w, parity_ref[...], slopes)
+    mask = mask_ref[...]
+    out_w, out_p, counts = scrub_tile(words_ref[...] ^ mask,  # the injection
+                                      parity_ref[...], slopes, scratch)
     out_w_ref[...] = out_w
     out_p_ref[...] = out_p
-    stats_ref[...] = jnp.stack([
-        popcount32(mask_ref[...]).sum(),
-        data_err.astype(jnp.int32).sum(),
-        parity_err.astype(jnp.int32).sum(),
-        uncorrectable.astype(jnp.int32).sum(),
-    ]).reshape(1, 4)
+    tiles.accumulate_stats(stats_ref, [popcount32(mask).sum()] + counts)
 
 
-@functools.partial(jax.jit, static_argnames=("slopes", "block_m", "interpret"))
+@functools.partial(jax.jit, static_argnames=("slopes", "rows", "interpret"))
 def inject_scrub_kernel(words: jax.Array, parity: jax.Array, mask: jax.Array,
                         slopes: Tuple[int, ...] = (1, 2, -1),
-                        block_m: int = 256, interpret: bool = True):
-    """Fused inject+scrub: words/mask (n_blocks, 32) + parity (n_blocks, F)
-    uint32 -> (corrected words, corrected parity, per-tile stats (grid, 4)).
+                        rows: int = 512, interpret: bool = False):
+    """Fused inject+scrub: words/mask (n_blocks / 4, 128) + parity
+    (F, n_blocks) uint32 in the `arena_tiles` layout -> (corrected words,
+    corrected parity, stats tile (8, 128) int32).
 
-    stats columns: injected, corrected, parity_fixed, uncorrectable.
-    Requires slopes to contain the locating pair (1, 2).
+    stats row 0, lanes 0..3: injected, corrected, parity_fixed,
+    uncorrectable, summed over the grid.  Requires slopes to contain the
+    locating pair (1, 2).
     """
     assert 1 in slopes and 2 in slopes, slopes
-    n_blocks, F = words.shape[0], len(slopes)
-    bm = min(block_m, n_blocks)
-    assert n_blocks % bm == 0, (n_blocks, bm)
-    grid = n_blocks // bm
+    n_rows, F = words.shape[0], len(slopes)
+    assert n_rows % rows == 0, (n_rows, rows)
     return pl.pallas_call(
         functools.partial(_inject_scrub_kernel, slopes=slopes),
-        grid=(grid,),
-        in_specs=[pl.BlockSpec((bm, BLOCK), lambda i: (i, 0)),
-                  pl.BlockSpec((bm, F), lambda i: (i, 0)),
-                  pl.BlockSpec((bm, BLOCK), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((bm, BLOCK), lambda i: (i, 0)),
-                   pl.BlockSpec((bm, F), lambda i: (i, 0)),
-                   pl.BlockSpec((1, 4), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n_blocks, BLOCK), jnp.uint32),
-                   jax.ShapeDtypeStruct((n_blocks, F), jnp.uint32),
-                   jax.ShapeDtypeStruct((grid, 4), jnp.int32)],
+        grid=(n_rows // rows,),
+        in_specs=[tiles.words_spec(rows), tiles.parity_spec(F, rows),
+                  tiles.words_spec(rows)],
+        out_specs=[tiles.words_spec(rows), tiles.parity_spec(F, rows),
+                   tiles.STATS_SPEC],
+        out_shape=[jax.ShapeDtypeStruct(words.shape, jnp.uint32),
+                   jax.ShapeDtypeStruct(parity.shape, jnp.uint32),
+                   jax.ShapeDtypeStruct(tiles.STATS_SHAPE, jnp.int32)],
+        scratch_shapes=[tiles.parity_scratch(rows)],
+        compiler_params=tiles.SEQUENTIAL,
         interpret=interpret,
     )(words, parity, mask)

@@ -13,12 +13,15 @@ from typing import Callable, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import arena_tiles as tiles
 from .. import use_interpret
-from .kernel import BLOCK, inject_scrub_kernel
+from .kernel import inject_scrub_kernel
+
+BLOCK = tiles.BLOCK
 
 
 def inject_scrub(buf: jax.Array, parity: jax.Array, mask: jax.Array,
-                 slopes: Tuple[int, ...] = (1, 2, -1), block_m: int = 256,
+                 slopes: Tuple[int, ...] = (1, 2, -1), block_rows: int = 512,
                  interpret: bool | None = None):
     """Fused corrupt+scrub of a flat uint32 buffer against its parity table.
 
@@ -28,26 +31,23 @@ def inject_scrub(buf: jax.Array, parity: jax.Array, mask: jax.Array,
     """
     assert buf.ndim == 1 and buf.shape[0] % BLOCK == 0
     assert mask.shape == buf.shape, (mask.shape, buf.shape)
-    words = buf.reshape(-1, BLOCK)
-    mwords = mask.reshape(-1, BLOCK)
-    n = words.shape[0]
+    n = buf.shape[0] // BLOCK
     assert parity.shape == (n, len(slopes)), (parity.shape, n)
     if n == 0:
         return buf, parity, jnp.zeros((4,), jnp.int32)
-    pad = (-n) % block_m if n > block_m else 0
-    if pad:
-        words = jnp.pad(words, ((0, pad), (0, 0)))
-        mwords = jnp.pad(mwords, ((0, pad), (0, 0)))
-        parity = jnp.pad(parity, ((0, pad), (0, 0)))
+    rows, nb = tiles.plan(n, block_rows)
     fixed, par2, stats = inject_scrub_kernel(
-        words, parity, mwords, slopes=tuple(slopes), block_m=block_m,
+        tiles.words_view(buf, nb), tiles.parity_to_kernel(parity, nb),
+        tiles.words_view(mask, nb), slopes=tuple(slopes), rows=rows,
         interpret=use_interpret() if interpret is None else interpret)
-    return fixed[:n].reshape(-1), par2[:n], stats.sum(axis=0)
+    return (tiles.flat(fixed, n), tiles.parity_from_kernel(par2, n),
+            stats[0, :4])
 
 
 def inject_scrub_sharded(buf: jax.Array, parity: jax.Array, mask: jax.Array,
                          slopes: Tuple[int, ...] = (1, 2, -1),
-                         block_m: int = 256, interpret: bool | None = None,
+                         block_rows: int = 512,
+                         interpret: bool | None = None,
                          *, mesh=None,
                          axes: Sequence[str] = ("copy", "data", "model"),
                          local_op: Optional[Callable] = None):
@@ -58,7 +58,7 @@ def inject_scrub_sharded(buf: jax.Array, parity: jax.Array, mask: jax.Array,
     if local_op is None:
         def local_op(b, p, m):
             return inject_scrub(b, p, m, slopes=tuple(slopes),
-                                block_m=block_m, interpret=interpret)
+                                block_rows=block_rows, interpret=interpret)
     if mesh is None:
         return local_op(buf, parity, mask)
     from ..sharded import shard_scrub

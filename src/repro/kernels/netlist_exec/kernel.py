@@ -76,7 +76,7 @@ def _inject_kernel(rows_ref, keep_ref, flip_ref, state_in_ref,
 def netlist_exec_kernel(rows_in: jax.Array, state: jax.Array,
                         keep: Optional[jax.Array] = None,
                         flip: Optional[jax.Array] = None, *, base: int,
-                        tile_tw: int = 8, interpret: bool = True) -> jax.Array:
+                        tile_tw: int = 8, interpret: bool = False) -> jax.Array:
     """rows_in: (L, W, 3) int32 remapped input rows per level; state:
     (base + L*W, tw) uint32 trial-packed wire state (tw divisible by
     tile_tw); keep/flip: optional (L, W, tw) uint32 corruption masks

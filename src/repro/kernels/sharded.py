@@ -5,7 +5,7 @@ scrub op is *block-local*: block i's syndrome depends only on block i's
 words and parity row.  So sharding the block axis across the whole mesh and
 running the single-device op per shard is exactly the single-device result
 — no halo, no re-tiling — and the (3,)/(4,) int32 stat vectors sum exactly
-under `psum`.  `check_rep=False` is required because pallas_call has no
+under `psum`.  `check_vma=False` is required because pallas_call has no
 replication rule; correctness is carried by the block-locality argument
 above, not by shard_map's rep checker.
 """
@@ -15,7 +15,6 @@ from typing import Callable, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .diag_parity.kernel import BLOCK
@@ -61,9 +60,9 @@ def shard_scrub(local_fn: Callable, mesh: Mesh, axes: Sequence[str],
         fixed, par2, counts = local_fn(b, p, *ex)
         return fixed, par2, jax.lax.psum(counts, axes)
 
-    fixed, par2, counts = shard_map(
+    fixed, par2, counts = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axspec), P(axspec)) + (P(axspec),) * len(flat_extra),
         out_specs=(P(axspec), P(axspec), P()),
-        check_rep=False)(buf, parity, *flat_extra)
+        check_vma=False)(buf, parity, *flat_extra)
     return fixed[:nb * BLOCK], par2[:nb], counts

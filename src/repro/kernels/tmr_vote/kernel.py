@@ -23,7 +23,7 @@ def _kernel(a_ref, b_ref, c_ref, o_ref):
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "interpret"))
 def vote_kernel(a: jax.Array, b: jax.Array, c: jax.Array,
                 block_m: int = 256, block_n: int = 512,
-                interpret: bool = True) -> jax.Array:
+                interpret: bool = False) -> jax.Array:
     """a/b/c: (M, N) uint32 -> per-bit majority (M, N)."""
     M, N = a.shape
     bm, bn = min(block_m, M), min(block_n, N)

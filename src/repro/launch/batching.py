@@ -78,7 +78,7 @@ from ..obs import DEFAULT_REGISTRY, LatencyTimeline, MetricsRegistry
 from ..pshard import use_mesh_and_rules
 from ..reliability.backend import dispatch as _backend
 from ..reliability.scheme import ArenaEcc, Compose, Scheme
-from .engine import GenerationEngine
+from .engine import GenerationEngine, donate_argnums
 
 __all__ = ["BatchSpec", "Request", "RequestResult", "PagedKVPool",
            "ContinuousBatcher", "poisson_trace", "sequential_slot_steps"]
@@ -574,7 +574,7 @@ class ContinuousBatcher:
                                        touched(table, pos - chunk))
             return tok, out, pk, pv, pos, par, rcounts
 
-        donate = (1, 2, 3, 4, 5, 6) if jax.default_backend() != "cpu" else ()
+        donate = donate_argnums(1, 2, 3, 4, 5, 6)
         self._tick_fn = jax.jit(tick, donate_argnums=donate)
         return self._tick_fn
 
@@ -617,7 +617,7 @@ class ContinuousBatcher:
             par = self._refresh_parity(pk, pv, parity, table_row)
             return tok, out, pk, pv, pos, par
 
-        donate = (1, 2, 3, 4, 5, 6) if jax.default_backend() != "cpu" else ()
+        donate = donate_argnums(1, 2, 3, 4, 5, 6)
         fn = jax.jit(admit, donate_argnums=donate)
         self._admit_fns[plen] = fn
         return fn
@@ -625,11 +625,14 @@ class ContinuousBatcher:
     # -- scheduler ------------------------------------------------------------
 
     def prepare(self, params: Any, key: Optional[jax.Array] = None,
-                fault=None, dt: float = 1.0) -> Dict[str, Any]:
+                fault=None, dt: float = 1.0, *, donate: bool = False
+                ) -> Dict[str, Any]:
         """Build the protected serving store (engine.prepare: identical
-        fault keys and scrubs as whole-batch serving) and attach it."""
+        fault keys and scrubs as whole-batch serving) and attach it.  With
+        `donate`, the store takes the memory of `params`, which the caller
+        must not read again."""
         self.store, prep = self.engine.prepare(params, key=key, fault=fault,
-                                               dt=dt)
+                                               dt=dt, donate=donate)
         self._prep = dict(prep)
         return prep
 

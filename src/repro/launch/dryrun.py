@@ -11,6 +11,7 @@ os.environ["XLA_FLAGS"] = os.environ.get(
 # ^ MUST run before any other import: jax locks the device count on first init.
 
 import argparse
+import functools
 import json
 import sys
 import time
@@ -132,10 +133,11 @@ def run_engine_cell(arch: str, scheme_spec: str = "tmr-parallel",
     """Lower + compile the sharded generation engine's hot program on the
     dedicated TMR serving mesh (copy=3 x data=5 x model=16 — 240 chips of a
     256-chip pod, DESIGN.md §14) with abstract sharded inputs: proves the
-    copy-folded store, KV caches and cross-replica vote collectives produce
-    a coherent program and reports its per-device memory/collective
-    footprint without allocating a single parameter."""
-    from jax.sharding import NamedSharding
+    copy-folded store and KV caches produce a coherent program and reports
+    its per-device memory/collective footprint without allocating a single
+    parameter.  Concurrent TMR there runs each copy as a replica, so the
+    hot program is copy 0's single-copy scan on its 5x16 group."""
+    from jax.sharding import NamedSharding, PartitionSpec
 
     from ..models.params import abstractify, partition_specs
     from ..models.transformer import model_specs
@@ -165,9 +167,23 @@ def run_engine_cell(arch: str, scheme_spec: str = "tmr-parallel",
             sharding=NamedSharding(emesh, spec_for(
                 (batch, prompt_len), ("batch", None), emesh, rules)))
         fns = engine._build(prompt_len)
-        fn = fns["tmr_scan"] if engine.copy_axis else fns["single_scan"]
+        if engine._replicated:
+            group = engine._copy_meshes()[0]
+            store = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    (1,) + a.shape[1:], a.dtype, sharding=NamedSharding(
+                        group, PartitionSpec(None, *a.sharding.spec[1:]))),
+                store)
+            tokens = jax.ShapeDtypeStruct(tokens.shape, tokens.dtype,
+                                          sharding=NamedSharding(
+                group, spec_for(tokens.shape, ("batch", None), group,
+                                rules)))
+            fn = functools.partial(fns["replica_scan"].lower, 0)
+        else:
+            fn = (fns["tmr_scan"] if engine.copy_axis
+                  else fns["single_scan"]).lower
         t0 = time.time()
-        lowered = fn.lower(store, {"tokens": tokens})
+        lowered = fn(store, {"tokens": tokens})
         t_lower = time.time() - t0
         t0 = time.time()
         compiled = lowered.compile()
@@ -203,7 +219,8 @@ def main() -> None:
                     help='JSON sharding-rule overrides, e.g. \'{"kv_seq": []}\'')
     ap.add_argument("--save-hlo", default=None)
     ap.add_argument("--engine-cell", action="store_true",
-                    help="lower the sharded generation engine (tmr_scan) on "
+                    help="lower the sharded generation engine (one copy's "
+                         "program) on "
                          "the copy x data x model TMR serving mesh instead "
                          "of the train/prefill/decode cells")
     ap.add_argument("--scheme", default="tmr-parallel",

@@ -26,7 +26,8 @@ compiled generation under any protection scheme:
 * **mesh execution** — constructed with ``mesh=``, the engine shards the
   store/caches/batch via the logical-axis rules, folds the TMR copy axis
   onto data-replica groups (`launch.mesh.fold_copy_axis`) so parallel
-  disciplines reuse replicas that already exist, and runs arena scrubs as
+  disciplines reuse replicas that already exist (each group runs its copy
+  as the single-copy program, voted at the end), and runs arena scrubs as
   per-shard shard_map launches with psum'd counters (DESIGN.md §14) —
   bit-exact against the single-device engine under identical fault keys.
 
@@ -42,13 +43,15 @@ The interpreted reference survives as ``execution='loop'`` /
 """
 from __future__ import annotations
 
+import functools
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from jax.sharding import NamedSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..models.config import ModelConfig
 from ..models.params import partition_specs
@@ -67,18 +70,68 @@ from .mesh import fold_copy_axis
 __all__ = ["GenerationEngine", "fetch_telemetry", "make_eval_hook"]
 
 
-def _stack_copies(copies) -> Any:
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *copies)
+#: Donate on the CPU backend too.  Off by default, where donation saves
+#: nothing worth its risk; tests turn it on so that a read of a donated
+#: buffer fails on the CPU as it would on an accelerator.
+DONATE_ON_CPU = False
+
+
+def donate_argnums(*argnums: int) -> Tuple[int, ...]:
+    """`argnums` off the CPU, where XLA reuses donated buffers in place;
+    nothing on the CPU backend unless `DONATE_ON_CPU`."""
+    return argnums if DONATE_ON_CPU or jax.default_backend() != "cpu" else ()
+
+
+@functools.lru_cache(maxsize=None)
+def _leaf_program(donate: Tuple[int, ...]):
+    """The compiled program that builds one leaf of a serving store,
+    ``(x, key, ecc, fault, dt) -> (leaf, (3,) counts or None)``: corrupt by
+    `fault` (None: clean), then encode and scrub by `ecc` (None:
+    unprotected), slab by slab.  `donate` is `donate_argnums(0)` when x's
+    memory may become the leaf's, else ()."""
+    def run(x, key, ecc, fault, dt):
+        def slab(words, offset):
+            dirty = words if fault is None \
+                else fault.corrupt_words(words, key, dt, offset=offset)
+            if ecc is None:
+                return dirty, ()
+            pad = (0, (-words.shape[0]) % arena.BLOCK)
+            parity = ecc.encode_arena(jnp.pad(words, pad))
+            fixed, _, counts = ecc.scrub_arena(jnp.pad(dirty, pad), parity)
+            return fixed[:words.shape[0]], counts
+
+        y, counts = arena.map_leaf_words(x, slab)
+        return y, counts if ecc is not None else None
+
+    return jax.jit(run, static_argnums=(2, 3, 4), donate_argnums=donate)
+
+
+def _stack_leaf(copies, sharding=None) -> jax.Array:
+    """Stack one leaf's three copies on the leading copy axis: on the
+    default device, or — given the copy-sharded store `sharding` — straight
+    from each copy's home device into that layout."""
+    if sharding is None:
+        return jnp.stack(copies)
+    shape = (len(copies),) + copies[0].shape
+    shards = []
+    for dev, idx in sharding.addressable_devices_indices_map(shape).items():
+        c = idx[0].start or 0
+        piece = copies[c][idx[1:]] \
+            if any(s != slice(None) for s in idx[1:]) else copies[c]
+        shards.append(jax.device_put(piece[None], dev))
+    return jax.make_array_from_single_device_arrays(shape, sharding, shards)
 
 
 def _copy(stacked, i: int) -> Any:
     return jax.tree.map(lambda x: x[i], stacked)
 
 
-def _disagreements(t3: jax.Array) -> jax.Array:
-    """Token positions where the three copies do not all agree (int32)."""
+def _disagreements(t3: jax.Array, axis=None) -> jax.Array:
+    """Token positions where the three copies do not all agree (int32),
+    in total or summed over `axis` of one copy (axis=0 of (3, B, gen)
+    sequences: the per-step `tmr_step_disagreements` series)."""
     d = (t3[0] != t3[1]) | (t3[0] != t3[2]) | (t3[1] != t3[2])
-    return d.sum(dtype=jnp.int32)
+    return d.sum(axis=axis, dtype=jnp.int32)
 
 
 def _with_emitted(tokens: jax.Array,
@@ -216,6 +269,69 @@ class GenerationEngine:
             out["mmpu_events"] = jnp.asarray(cost.n_events, jnp.int32)
         return out
 
+    @property
+    def _replicated(self) -> bool:
+        """Do the copies run as replicas: each copy the single-copy
+        program on its own copy group, voted at the end?  So on every
+        copy-folded mesh unless the scan votes inside (`vote_every`), which
+        needs all copies in one program.  A copy then computes bit for bit
+        what its group computes for one model alone (a batched,
+        partitioned program fuses and rounds differently on a TPU)."""
+        return not self.vote_every and self._copy_meshes() is not None
+
+    def _replicas(self, store) -> list:
+        """Copy c of a copy-sharded stacked store as a (1, ...) array on
+        copy group c's sub-mesh, made of the same device buffers."""
+        def part(x, c, mesh):
+            shards = [s.data for s in x.addressable_shards
+                      if (s.index[0].start or 0) == c]
+            spec = PartitionSpec(None, *x.sharding.spec[1:])
+            return jax.make_array_from_single_device_arrays(
+                (1,) + x.shape[1:], NamedSharding(mesh, spec), shards)
+        return [jax.tree.map(lambda x: part(x, c, m), store)
+                for c, m in enumerate(self._copy_meshes())]
+
+    def _replica(self, f: Callable) -> Callable:
+        """`f(params, ...)` as a replica program ``(c, part, ...)``: it
+        takes copy c's (1, ...) part and traces under copy group c's
+        sub-mesh — with no mesh at all for a one-device group, so that it
+        is the plain single-chip program."""
+        def run(c, part, *args):
+            mesh = self._copy_meshes()[c]
+            with use_mesh_and_rules(mesh if mesh.size > 1 else None,
+                                    self.rules):
+                return f(jax.tree.map(lambda a: a[0], part), *args)
+        return run
+
+    def _copy_batch(self, batch, c: int):
+        """The batch placed on copy group c, as the replica programs take
+        it."""
+        mesh = self._copy_meshes()[c]
+        if mesh.size == 1:
+            return jax.device_put(batch, mesh.devices.flat[0])
+        return self._shard_batch(batch, mesh)
+
+    def _replica_vote(self, seqs) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """Vote per-copy (B, n) sequences gathered on one device."""
+        seq3 = jnp.stack([jax.device_put(q, self._copy_devices()[0])
+                          for q in seqs])
+        tokens = self._tmr()._vote()(seq3[0], seq3[1], seq3[2])
+        return tokens, {"tmr_step_disagreements": _disagreements(seq3, 0),
+                        "tmr_final_disagreements": _disagreements(seq3)}
+
+    def _voter(self) -> Callable:
+        """The scheme's per-bit vote of three same-shape arrays.  On a
+        multi-device exec mesh it runs per device inside `shard_map` on
+        operands replicated to every device: XLA cannot partition a Mosaic
+        kernel, and a vote needs all three copies in one place anyway."""
+        vote = self._tmr()._vote()
+        mesh = self.exec_mesh
+        if mesh is None or mesh.size == 1:
+            return vote
+        return jax.shard_map(vote, mesh=mesh,
+                             in_specs=(PartitionSpec(),) * 3,
+                             out_specs=PartitionSpec(), check_vma=False)
+
     def _discipline(self) -> Optional[str]:
         tmr = self._tmr()
         return tmr.discipline if tmr is not None else None
@@ -258,10 +374,12 @@ class GenerationEngine:
         return jax.device_put(
             store, self._param_shardings(stacked=self.copy_axis))
 
-    def _shard_batch(self, batch: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
-        if self.mesh is None:
+    def _shard_batch(self, batch: Dict[str, jax.Array],
+                     mesh=None) -> Dict[str, jax.Array]:
+        """The batch over `mesh` (default: the exec mesh, if any)."""
+        mesh = mesh if mesh is not None else self.exec_mesh
+        if mesh is None:
             return batch
-        mesh = self.exec_mesh
         from ..pshard import spec_for
         return {
             k: jax.device_put(v, NamedSharding(mesh, spec_for(
@@ -269,8 +387,28 @@ class GenerationEngine:
                 self.rules)))
             for k, v in batch.items()}
 
+    def _copy_meshes(self) -> Optional[list]:
+        """Each TMR copy's group of a copy-folded exec mesh, as a mesh over
+        the remaining axes; None on any other mesh."""
+        mesh = self.exec_mesh
+        if mesh is None or "copy" not in mesh.axis_names:
+            return None
+        ax = mesh.axis_names.index("copy")
+        names, types = zip(*[(n, t) for n, t in zip(mesh.axis_names,
+                                                    mesh.axis_types)
+                             if n != "copy"])
+        return [Mesh(np.take(mesh.devices, c, axis=ax), names,
+                     axis_types=types) for c in range(mesh.shape["copy"])]
+
+    def _copy_devices(self):
+        """One home device per TMR copy on a copy-folded exec mesh (the
+        first device of each copy group), else None."""
+        meshes = self._copy_meshes()
+        return meshes and [m.devices.flat[0] for m in meshes]
+
     def prepare(self, params: Any, key: Optional[jax.Array] = None,
-                fault=None, dt: float = 1.0) -> Tuple[Any, Dict[str, Any]]:
+                fault=None, dt: float = 1.0, *, donate: bool = False
+                ) -> Tuple[Any, Dict[str, Any]]:
         """Build the scheme's serving store from clean params.
 
         Applies one exposure interval of `fault` to every held data copy
@@ -278,54 +416,63 @@ class GenerationEngine:
         convention, so engine stores are bit-identical to the legacy
         driver's under the same seed), then applies the scheme's
         *storage-side* protection: ECC schemes scrub the corrupted
-        store(s) — for `Compose` all three copies in one fused launch —
-        and TMR schemes stack the copies on the leading copy axis.
+        store(s), and TMR schemes stack the copies on the leading copy
+        axis.
+
+        The store is built leaf by leaf — encode, corrupt and scrub of one
+        leaf in one compiled program — so the model is never held packed,
+        corrupted or unpacked as a whole beside itself.  Because every leaf
+        starts on an ECC block boundary, the per-leaf scrubs are exactly
+        the arena-wide scrub: same bits, same counts.  With `donate`, the
+        programs take each params leaf's memory for the store off the CPU,
+        so that one model's worth of memory holds both: the caller must not
+        read `params` after the call.  Without it, `params` stays intact.
 
         Returns (store, prep_telemetry); the telemetry values are
         on-device scalars (fetch once via `fetch_telemetry`).
 
-        With a mesh, the finished store is placed by `shard_store` and the
-        arena scrubs run shard-wise (`scrub_sharded`) with psum'd counters
-        — same bits, same counts as the single-device path.
+        With a mesh, the finished store is placed by `shard_store`.  On a
+        copy-folded mesh each copy is built on its own copy group's device
+        and the stacked store is assembled from those shards, so no device
+        ever holds more than one copy.
         """
         scheme = self.scheme
-        mesh = self.exec_mesh
-
-        def corrupt(i: int) -> Any:
-            if fault is None:
-                return params
-            return fault.corrupt(params, jax.random.fold_in(key, 100 + i), dt)
-
-        def place(store, telem):
-            return self.shard_store(store), telem
-
-        with use_mesh_and_rules(mesh, self.rules):
-            if isinstance(scheme, Unprotected):
-                return place(corrupt(0), {})
-            if isinstance(scheme, ArenaEcc):
-                prot = scheme.protect(params)
-                fixed, rep = scheme.scrub(scheme.adopt(corrupt(0),
-                                                       prot.redundancy),
-                                          mesh=mesh)
-                return place(fixed.payload,
-                             {"ecc_corrected": rep.corrected,
-                              "ecc_parity_fixed": rep.parity_fixed,
-                              "ecc_uncorrectable": rep.uncorrectable})
-            if isinstance(scheme, Tmr):
-                return place(_stack_copies([corrupt(i) for i in range(3)]),
-                             {})
-            if isinstance(scheme, Compose):
-                buf, spec = arena.pack(params)
-                parity = scheme.ecc._encode(buf)
-                packed = [arena.pack(corrupt(i))[0] for i in range(3)]
-                bufs, _, counts = scheme.ecc.scrub_copies(
-                    packed, [parity] * 3, mesh=mesh)
-                copies = [arena.unpack(b, spec) for b in bufs]
-                return place(_stack_copies(copies),
-                             {"ecc_corrected": counts[0],
-                              "ecc_parity_fixed": counts[1],
-                              "ecc_uncorrectable": counts[2]})
-        raise ValueError(f"unhandled scheme {scheme!r}")
+        ecc = scheme if isinstance(scheme, ArenaEcc) \
+            else scheme.ecc if isinstance(scheme, Compose) else None
+        n_copies = 3 if self.copy_axis else 1
+        homes = self._copy_devices()
+        shardings = homes and jax.tree.leaves(
+            self._param_shardings(stacked=True))
+        leaves, treedef = jax.tree.flatten(params)
+        keys = [jax.random.split(jax.random.fold_in(key, 100 + i),
+                                 len(leaves)) if fault is not None else None
+                for i in range(n_copies)]
+        out, counts = [], []
+        for j, x in enumerate(leaves):
+            copies = [None] * n_copies
+            for i in reversed(range(n_copies)):   # copy 0 may consume x
+                moved = homes is not None and homes[i] not in x.devices()
+                xi = jax.device_put(x, homes[i]) if moved else x
+                if fault is None and ecc is None:
+                    y, c = xi, None
+                else:
+                    # a copy moved to its home device is the engine's own
+                    own = moved or (donate and i == 0)
+                    y, c = _leaf_program(donate_argnums(0) if own else ())(
+                        xi, None if keys[i] is None else keys[i][j], ecc,
+                        fault, dt)
+                copies[i] = y
+                if c is not None:
+                    counts.append(c)
+            out.append(copies[0] if not self.copy_axis
+                       else _stack_leaf(copies, shardings and shardings[j]))
+        store = self.shard_store(treedef.unflatten(out))
+        if ecc is None:
+            return store, {}
+        total = sum(jax.device_put(c, jax.devices()[0]) for c in counts)
+        return store, {"ecc_corrected": total[0],
+                       "ecc_parity_fixed": total[1],
+                       "ecc_uncorrectable": total[2]}
 
     # -- compiled paths -----------------------------------------------------
 
@@ -337,7 +484,7 @@ class GenerationEngine:
         prefill = make_prefill_step(cfg, cache_len=cache_len)
         decode = make_decode_step(cfg)
         tmr = self._tmr()
-        vote = tmr._vote() if tmr is not None else None
+        vote = self._voter() if tmr is not None else None
         vote_every, vote_cache = self.vote_every, self.vote_cache
 
         def single_scan(params, batch):
@@ -356,10 +503,12 @@ class GenerationEngine:
 
         # concurrent copy-axis evaluator for 'parallel'/'semi_parallel':
         # vmap prefill+scan over the stacked copies (one batched launch).
-        # On a copy-folded mesh (exec_mesh) the stacked axis is sharded
-        # over three disjoint replica groups — each group runs ONE copy —
-        # and the per-step vote/disagreement reads become tiny cross-
-        # replica collectives on the token ids (DESIGN.md §14).  The
+        # On a copy-folded mesh it runs only when the scan votes inside
+        # (vote_every): the stacked axis is sharded over three disjoint
+        # replica groups — each group runs ONE copy — and the per-step
+        # vote/disagreement reads become tiny cross-replica collectives on
+        # the token ids (DESIGN.md §14); otherwise the copies run as
+        # replicas (`_replicated`).  The
         # 'serial' discipline never enters this path — it re-runs the
         # single-copy scan per copy (generate_scan), keeping the paper's
         # 1x-area property: no 3x activations/cache in flight.
@@ -407,15 +556,25 @@ class GenerationEngine:
 
         # donation: the Python-loop path re-launches decode per token; on
         # accelerators the cache carry is donated so each step updates the
-        # KV buffers in place (CPU has no donation — skip the warning spam)
-        donate = (2,) if jax.default_backend() != "cpu" else ()
+        # KV buffers in place (`donate_argnums`: off the CPU only)
+        donate = donate_argnums(2)
         concurrent = tmr is not None and tmr.discipline != "serial"
+        replicas = self._replicated
+        vmapped = concurrent and not replicas
         fns = {
             "prefill": jax.jit(prefill),
             "decode": jax.jit(decode, donate_argnums=donate),
             "single_scan": jax.jit(single_scan),
-            "tmr_prefill": jax.jit(tmr_prefill) if concurrent else None,
-            "tmr_scan": jax.jit(tmr_scan) if concurrent else None,
+            "tmr_prefill": jax.jit(tmr_prefill) if vmapped else None,
+            "tmr_scan": jax.jit(tmr_scan) if vmapped else None,
+            # (c, copy c's part, ...): the single-copy program on copy
+            # group c
+            "replica_prefill": (jax.jit(self._replica(prefill),
+                                        static_argnums=0)
+                                if replicas else None),
+            "replica_scan": (jax.jit(self._replica(single_scan),
+                                     static_argnums=0)
+                             if replicas else None),
         }
         self._built[prompt_len] = fns
         return fns
@@ -433,7 +592,7 @@ class GenerationEngine:
             return self._chunk_built[n]
         decode = make_decode_step(self.cfg)
         tmr = self._tmr()
-        vote = tmr._vote() if tmr is not None else None
+        vote = self._voter() if tmr is not None else None
         vote_every, vote_cache = self.vote_every, self.vote_cache
 
         def chunk_scan(params, tok, cache):
@@ -471,12 +630,17 @@ class GenerationEngine:
                 body, (tok3, cache3), offset + jnp.arange(n))
             return tok3, cache3, steps3, dis
 
-        donate = (2,) if jax.default_backend() != "cpu" else ()
+        donate = donate_argnums(2)
         concurrent = tmr is not None and tmr.discipline != "serial"
+        replicas = self._replicated
         fns = {
             "chunk": jax.jit(chunk_scan, donate_argnums=donate),
+            "replica_chunk": (jax.jit(self._replica(chunk_scan),
+                                      static_argnums=0,
+                                      donate_argnums=donate_argnums(3))
+                              if replicas else None),
             "tmr_chunk": (jax.jit(tmr_chunk, donate_argnums=donate)
-                          if concurrent else None),
+                          if concurrent and not replicas else None),
         }
         self._chunk_built[n] = fns
         while len(self._chunk_built) > self.CHUNK_CACHE_MAX:
@@ -533,9 +697,13 @@ class GenerationEngine:
             elif self._discipline() == "serial":
                 outs = [fns["single_scan"](_copy(store, i), batch)[0]
                         for i in range(3)]
-                tokens = self._tmr()._vote()(*outs)
+                tokens = self._voter()(*outs)
                 telem = {"tmr_final_disagreements":
                          _disagreements(jnp.stack(outs))}
+            elif self._replicated:
+                tokens, telem = self._replica_vote([
+                    fns["replica_scan"](c, p, self._copy_batch(batch, c))[0]
+                    for c, p in enumerate(self._replicas(store))])
             else:
                 tokens, telem = fns["tmr_scan"](store, batch)
             return tokens, self._finish_telemetry(tokens, telem)
@@ -602,7 +770,10 @@ class GenerationEngine:
         """Chunked 'parallel'/'semi' TMR: vmapped prefill + chunked vmapped
         scans; per-step disagreements and vote points identical to the
         unchunked tmr_scan (global-step offset threading)."""
-        vote = self._tmr()._vote()
+        if self._replicated:
+            return self._chunked_replicas(self._replicas(store), batch, fns,
+                                          chunk, timeline, tracer)
+        vote = self._voter()
         with tracer.trace("tmr_prefill", tokens=1):
             tok3, _, cache3 = fns["tmr_prefill"](store, batch)
             jax.block_until_ready(tok3)
@@ -628,13 +799,38 @@ class GenerationEngine:
             "tmr_step_disagreements": jnp.concatenate(dis_parts),
             "tmr_final_disagreements": _disagreements(seq3)}
 
+    def _chunked_replicas(self, parts, batch, fns, chunk, timeline,
+                          tracer):
+        """Chunked TMR with one replica per copy group: each chunk
+        launches on all copy groups at once, then the per-copy sequences
+        are voted on one device."""
+        with tracer.trace("tmr_prefill", tokens=1):
+            outs = [fns["replica_prefill"](c, p, self._copy_batch(batch, c))
+                    for c, p in enumerate(parts)]
+            toks = [o[0] for o in outs]
+            caches = [o[2] for o in outs]
+            jax.block_until_ready(toks)
+        timeline.mark(1)
+        seqs = [[t] for t in toks]
+        for n in self._chunk_sizes(chunk):
+            with tracer.trace("tmr_decode_chunk", tokens=n):
+                for c, p in enumerate(parts):
+                    toks[c], caches[c], steps = \
+                        self._build_chunk(n)["replica_chunk"](
+                            c, p, toks[c], caches[c])
+                    seqs[c].append(steps)
+                jax.block_until_ready([q[-1] for q in seqs])
+            timeline.mark(n)
+        return self._replica_vote(
+            [jnp.concatenate(q, axis=1) for q in seqs])
+
     def _chunked_serial(self, store, batch, fns, chunk, timeline, tracer):
         """Chunked serial TMR: copies 0/1 run to completion (sequentially,
         no marks — only their token sequences are kept), then copy 2's
         launches each complete a *voted* chunk (majority vote is
         elementwise, so chunk-wise voting equals the final-sequence
         vote)."""
-        vote = self._tmr()._vote()
+        vote = self._voter()
         per_copy = []                      # copies 0, 1: [tok0, chunk, ...]
         for i in range(2):
             params = _copy(store, i)
@@ -688,7 +884,7 @@ class GenerationEngine:
                 return tokens, self._finish_telemetry(tokens, {})
             outs = [one(_copy(store, i)) for i in range(3)]
             seq3 = jnp.stack(outs)
-            voted = self._tmr()._vote()(*outs)
+            voted = self._voter()(*outs)
             return voted, self._finish_telemetry(
                 voted, {"tmr_final_disagreements": _disagreements(seq3)})
 
@@ -704,10 +900,15 @@ class GenerationEngine:
             if self._discipline() == "serial":
                 toks = [fns["prefill"](_copy(store, i), batch)[0]
                         for i in range(3)]
+            elif self._replicated:
+                return self._replica_vote([
+                    fns["replica_prefill"](c, p,
+                                           self._copy_batch(batch, c))[0]
+                    for c, p in enumerate(self._replicas(store))])[0]
             else:
                 tok3, _, _ = fns["tmr_prefill"](store, batch)
                 toks = [tok3[0], tok3[1], tok3[2]]
-            return self._tmr()._vote()(*toks)
+            return self._voter()(*toks)
 
 
 def make_eval_hook(engine: GenerationEngine, batch: Dict[str, jax.Array]

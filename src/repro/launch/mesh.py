@@ -13,13 +13,30 @@ instead of tripling any one device's work.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_test_mesh", "make_tmr_serving_mesh",
            "fold_copy_axis", "require_devices"]
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    """A mesh with Auto axis types: the engine places arrays with
+    NamedShardings and pins activations with `with_sharding_constraint`
+    under an ambient mesh, which Explicit axes (the `jax.make_mesh` default
+    since JAX 0.8) reject.  A mesh over part of the host (3 chips of a 2x2
+    TPU, say) takes the first devices in order: `jax.make_mesh` orders
+    devices by the physical torus and cannot place a partial one."""
+    types = (AxisType.Auto,) * len(axes)
+    n = math.prod(shape)
+    if n < jax.device_count():
+        return Mesh(np.asarray(jax.devices()[:n]).reshape(shape), axes,
+                    axis_types=types)
+    return jax.make_mesh(shape, axes, axis_types=types)
 
 
 def require_devices(n: int, what: str) -> None:
@@ -42,13 +59,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     require_devices(2 * 16 * 16 if multi_pod else 16 * 16,
                     f"production mesh {'x'.join(map(str, shape))}")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2):
     """Small mesh for CPU sharding tests (requires forced host devices)."""
     require_devices(data * model, f"test mesh {data}x{model}")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_tmr_serving_mesh(copies: int = 3, data: int = 5, model: int = 16):
@@ -58,7 +75,7 @@ def make_tmr_serving_mesh(copies: int = 3, data: int = 5, model: int = 16):
     Equivalent to `fold_copy_axis(make_test_mesh(copies*data, model))`."""
     require_devices(copies * data * model,
                     f"TMR serving mesh {copies}x{data}x{model}")
-    return jax.make_mesh((copies, data, model), ("copy", "data", "model"))
+    return _auto_mesh((copies, data, model), ("copy", "data", "model"))
 
 
 def fold_copy_axis(mesh: Mesh, copies: int = 3) -> Optional[Mesh]:
@@ -80,4 +97,5 @@ def fold_copy_axis(mesh: Mesh, copies: int = 3) -> Optional[Mesh]:
         return None
     devices = mesh.devices.reshape(copies, d // copies,
                                    mesh.shape["model"])
-    return Mesh(devices, ("copy", "data", "model"))
+    return Mesh(devices, ("copy", "data", "model"),
+                axis_types=(AxisType.Auto,) * 3)

@@ -60,26 +60,31 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 import numpy as np
 
+from .. import compile_cache
 from ..configs import get_config, list_archs
 from ..faults import (FaultModel, RetentionDrift, StuckAtFaults,
                       TransientBitFlips)
 from ..models import params as P
 from ..models import transformer as T
 from ..obs import LatencyTimeline, Tracer
-from ..reliability import ArenaEcc, Compose, Tmr, Unprotected, \
-    parse_scheme, scheme_choices, scheme_help
+from ..reliability import Compose, Tmr, parse_scheme, scheme_choices, \
+    scheme_help
 from .batching import BatchSpec, ContinuousBatcher, Request, poisson_trace
 from .engine import GenerationEngine, fetch_telemetry
 from .mesh import make_test_mesh
 
 
-def _run_server(args, cfg, key, params, scheme, fault, mesh) -> None:
+def _run_server(args, cfg, key, params, scheme, fault, mesh
+                ) -> Dict[str, Any]:
     """Continuous-batching server: open-loop Poisson load through the
-    chunk-boundary scheduler over the paged ECC-protected KV pool."""
+    chunk-boundary scheduler over the paged ECC-protected KV pool.
+    Returns the trace, its results, the fetched telemetry and the batcher
+    (which still holds the store)."""
     chunk = args.chunk or 8
     spec = BatchSpec(slots=args.slots, page_tokens=args.page_tokens,
                      chunk=chunk, prompt_buckets=(args.prompt_len,),
@@ -95,7 +100,9 @@ def _run_server(args, cfg, key, params, scheme, fault, mesh) -> None:
             interval0=max(1, args.scrub_every or 32))
     with tracer.trace("prepare", scheme=scheme.name):
         prep = b.prepare(params, key=key,
-                         fault=fault if args.inject_p_bit else None)
+                         fault=fault if args.inject_p_bit else None,
+                         donate=True)
+    del params        # donated to the store
     trace = poisson_trace(args.requests, rate_rps=args.rate, spec=spec,
                           vocab=cfg.vocab, seed=args.seed)
     # compile the admit bucket and the tick program before the open-loop
@@ -103,8 +110,10 @@ def _run_server(args, cfg, key, params, scheme, fault, mesh) -> None:
     # would show up as a queue spike rather than honest latency
     warm = [Request(10**6 + i, t.prompt, min(2, t.gen))
             for i, t in enumerate(trace[:spec.slots])]
+    t_warm = time.time()
     with tracer.trace("warmup"):
         b.run(warm)
+    t_warm = time.time() - t_warm
 
     t0 = time.time()
     with tracer.trace("serve", requests=args.requests, rate=args.rate,
@@ -162,9 +171,16 @@ def _run_server(args, cfg, key, params, scheme, fault, mesh) -> None:
         if args.metrics:
             tracer.write_jsonl(args.metrics)
             print(f"[serve] metrics jsonl -> {args.metrics}")
+    return {"trace": trace, "results": results, "stats": stats,
+            "batcher": b, "warmup_s": t_warm, "serve_s": dt}
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> Dict[str, Any]:
+    """Serve as the command line says; returns what was served (server
+    mode: `_run_server`'s dict; otherwise the tokens, the clean-run
+    reference when faults were injected, the fetched telemetry, and the
+    engine with its store and batch, which a caller drops to free the
+    store)."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="qwen2.5-14b", choices=list_archs())
     ap.add_argument("--smoke", action="store_true")
@@ -243,7 +259,7 @@ def main() -> None:
                          "(default: paper)")
     ap.add_argument("--page-tokens", type=int, default=16,
                     help="server mode: tokens per KV pool page")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.engine == "loop" and (args.vote_every or args.vote_cache):
         ap.error("--vote-every/--vote-cache only apply to the scan engine "
@@ -285,7 +301,8 @@ def main() -> None:
     if args.smoke:
         cfg = cfg.smoke()
     key = jax.random.PRNGKey(args.seed)
-    params = P.materialize(key, T.model_specs(cfg))
+    # served weights live in the compute dtype every matmul casts to
+    params = P.materialize(key, T.model_specs(cfg), dtype=cfg.cdtype)
 
     batch = {"tokens": jax.random.randint(key, (args.batch, args.prompt_len),
                                           0, cfg.vocab)}
@@ -313,8 +330,7 @@ def main() -> None:
         mesh = make_test_mesh(data, model)
 
     if args.server:
-        _run_server(args, cfg, key, params, scheme, fault, mesh)
-        return
+        return _run_server(args, cfg, key, params, scheme, fault, mesh)
 
     tracer = Tracer(enabled=bool(args.trace or args.metrics))
     cost_spec = None
@@ -326,11 +342,24 @@ def main() -> None:
                               vote_cache=args.vote_cache,
                               execution=args.engine, mesh=mesh,
                               cost_spec=cost_spec)
+    ref = None
+    if args.inject_p_bit:
+        # the clean reference runs first: prepare takes the weights' memory
+        # for the store, so no clean copy is kept beside it.  off/ecc
+        # stores are plain params pytrees, so the engine's own compiled
+        # single-copy program serves it; copy-axis schemes need a
+        # single-copy engine
+        clean = GenerationEngine(cfg, gen=args.gen, execution=args.engine) \
+            if engine.copy_axis else engine
+        ref = np.asarray(clean.generate(params, batch)[0])
     with tracer.trace("prepare", scheme=scheme.name):
         store, prep = engine.prepare(
-            params, key=key, fault=fault if args.inject_p_bit else None)
+            params, key=key, fault=fault if args.inject_p_bit else None,
+            donate=True)
+    del params        # donated to the store
     # keep compile and prepare's async corrupt/scrub launches out of the
     # timed region: one untimed warmup generation, then drain the store
+    t_warm = time.time()
     with tracer.trace("warmup"):
         if args.chunk:
             jax.block_until_ready(
@@ -338,6 +367,7 @@ def main() -> None:
         else:
             jax.block_until_ready(engine.generate(store, batch)[0])
         store = jax.block_until_ready(store)
+    t_warm = time.time() - t_warm
 
     # timed region: no host syncs — telemetry stays on device until after
     timeline = None
@@ -354,13 +384,8 @@ def main() -> None:
 
     with tracer.trace("fetch_telemetry"):
         stats = fetch_telemetry({**prep, **telem})   # the single fetch
-    # off/ecc stores are plain params pytrees, so the timed engine's
-    # compiled single-copy program serves the clean reference without a
-    # recompile; copy-axis schemes need a fresh single-copy engine
-    clean = engine if isinstance(scheme, (Unprotected, ArenaEcc)) \
-        else GenerationEngine(cfg, gen=args.gen, execution=args.engine)
-    ref = clean.generate(params, batch)[0] if args.inject_p_bit else out
-    agree = float(np.asarray(out == ref).mean())
+    out = np.asarray(out)
+    agree = float((out == ref).mean()) if ref is not None else 1.0
     tok_s = args.batch * args.gen / dt
     mesh_desc = "single" if mesh is None else \
         "x".join(f"{a}={n}" for a, n in engine.exec_mesh.shape.items())
@@ -419,8 +444,12 @@ def main() -> None:
         if args.metrics:
             tracer.write_jsonl(args.metrics)
             print(f"[serve] metrics jsonl -> {args.metrics}")
-    print("[serve] sample:", np.asarray(out[0, :16]).tolist())
+    print("[serve] sample:", out[0, :16].tolist())
+    return {"tokens": out, "reference": ref, "stats": stats,
+            "warmup_s": t_warm, "generate_s": dt,
+            "engine": engine, "store": store, "batch": batch}
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

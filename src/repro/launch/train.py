@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import compile_cache
 from ..checkpoint import Checkpointer
 from ..configs import get_config, get_train_policy, list_archs
 from ..data.synthetic import SyntheticLM
@@ -137,4 +138,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -8,6 +8,7 @@ trees (jit in_shardings) — one source of truth for shape + sharding.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
@@ -38,22 +39,28 @@ def _is_spec(x) -> bool:
 
 
 def materialize(key: jax.Array, tree: Any, dtype=jnp.float32) -> Any:
-    """Create real parameter arrays from a Spec tree."""
+    """Create real parameter arrays from a Spec tree.  Each leaf is drawn
+    by one compiled program, so a bfloat16 leaf leaves no float32 copy
+    behind in device memory."""
     leaves, treedef = jax.tree.flatten(tree, is_leaf=_is_spec)
     keys = jax.random.split(key, len(leaves))
-    out = []
-    for k, s in zip(keys, leaves):
-        dt = s.resolved_dtype(dtype)
-        if s.init == "zeros":
-            out.append(jnp.zeros(s.shape, dt))
-        elif s.init == "ones":
-            out.append(jnp.ones(s.shape, dt))
-        elif s.init == "scaled":
-            fan_in = s.shape[0] if len(s.shape) > 1 else max(s.shape[0], 1)
-            out.append((jax.random.normal(k, s.shape) / jnp.sqrt(fan_in)).astype(dt))
-        else:
-            out.append((s.scale * jax.random.normal(k, s.shape)).astype(dt))
-    return treedef.unflatten(out)
+    return treedef.unflatten([_init_leaf(k, s, s.resolved_dtype(dtype))
+                              for k, s in zip(keys, leaves)])
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _init_leaf(key: jax.Array, s: Spec, dt) -> jax.Array:
+    if s.init == "zeros":
+        return jnp.zeros(s.shape, dt)
+    if s.init == "ones":
+        return jnp.ones(s.shape, dt)
+    # the barrier keeps XLA from folding the scale into the draw's own
+    # constants, so the values are bit-identical to drawing eagerly
+    x = jax.lax.optimization_barrier(jax.random.normal(key, s.shape))
+    if s.init == "scaled":
+        fan_in = s.shape[0] if len(s.shape) > 1 else max(s.shape[0], 1)
+        return (x / jnp.sqrt(jnp.float32(fan_in))).astype(dt)
+    return (s.scale * x).astype(dt)
 
 
 def abstractify(tree: Any, mesh, dtype=jnp.float32,

@@ -28,8 +28,9 @@ Every implementation is registered as a lazy loader so importing this
 module never drags in the Pallas kernel packages; `dispatch(op, impl)`
 imports on first use and caches the resolved callable.
 
-The Pallas interpret flag also lives here: `use_interpret()` reads
-``REPRO_PALLAS_INTERPRET`` (default on — this container is CPU-only) and
+The Pallas interpret flag also lives here: `use_interpret()` follows the
+platform — interpret mode on the CPU backend, where tests run the kernel
+bodies as jnp, and compiled Mosaic kernels on a TPU — and
 `kernels.use_interpret` delegates to it.
 """
 from __future__ import annotations
@@ -38,11 +39,12 @@ import os
 from types import SimpleNamespace
 from typing import Callable, Dict, Optional, Tuple
 
+import jax
+
 __all__ = ["register", "ops", "implementations", "default_impl", "resolve",
            "dispatch", "use_interpret", "ENV_VAR"]
 
 ENV_VAR = "REPRO_IMPL"
-_INTERPRET_ENV = "REPRO_PALLAS_INTERPRET"
 
 _LOADERS: Dict[str, Dict[str, Callable[[], Callable]]] = {}
 _DEFAULTS: Dict[str, str] = {}
@@ -50,9 +52,10 @@ _CACHE: Dict[Tuple[str, str], Callable] = {}
 
 
 def use_interpret() -> bool:
-    """Run Pallas kernels in interpret mode (CPU)?  Single env read for all
-    kernel packages; on a real TPU set REPRO_PALLAS_INTERPRET=0."""
-    return os.environ.get(_INTERPRET_ENV, "1") != "0"
+    """Run Pallas kernels in interpret mode?  Exactly when the default
+    backend is the CPU: there is no switch that would let an accelerator
+    run the interpreter in silence."""
+    return jax.default_backend() == "cpu"
 
 
 def register(op: str, impl: str, loader: Callable[[], Callable],

@@ -381,10 +381,9 @@ class ArenaEcc(Scheme):
         """
         n = bufs[0].shape[0]
         nb = parities[0].shape[0]
-        fixed, par2, counts = self._scrub(
-            jnp.concatenate(arena.canonical_parts(list(bufs))),
-            jnp.concatenate(arena.canonical_parts(list(parities))),
-            mesh=mesh)
+        fixed, par2, counts = self._scrub(jnp.concatenate(list(bufs)),
+                                          jnp.concatenate(list(parities)),
+                                          mesh=mesh)
         return ([fixed[i * n:(i + 1) * n] for i in range(len(bufs))],
                 [par2[i * nb:(i + 1) * nb] for i in range(len(parities))],
                 counts)

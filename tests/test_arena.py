@@ -79,3 +79,26 @@ def test_empty_pytree_protect_scrub():
     fixed, rep = store.scrub()
     assert int(rep.corrected) == 0 and int(rep.uncorrectable) == 0
     assert fixed.params == {}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("slab_words", [256, 1 << 24])
+def test_map_leaf_words_slabs_match_whole_leaf(key, dtype, slab_words):
+    """Walking a leaf in block-aligned slabs (one fori_loop, leaf rewritten
+    in place) gives the words and summed aux of one whole-leaf call, and
+    keeps every bit — NaN payloads included."""
+    from repro.faults import TransientBitFlips
+    x = jax.random.normal(key, (6, 16, 64)).astype(dtype)
+    model = TransientBitFlips(0.05)        # dense enough to make NaNs
+
+    def fn(words, offset):
+        bad = model.corrupt_words(words, key, offset=offset)
+        return bad, (bad != words).sum(dtype=jnp.int32)
+
+    got, flipped = jax.jit(lambda x: arena.map_leaf_words(
+        x, fn, slab_words=slab_words))(x)
+    words = arena.leaf_to_words(x)
+    want = model.corrupt_words(words, key)
+    np.testing.assert_array_equal(np.asarray(arena.leaf_to_words(got)),
+                                  np.asarray(want))
+    assert int(flipped) == int((want != words).sum()) > 0

@@ -276,7 +276,7 @@ def test_mesh_suite_subprocess():
                                        "src"))
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
-               PYTHONPATH=src)
+               JAX_PLATFORMS="cpu", PYTHONPATH=src)
     out = subprocess.run(
         [sys.executable, "-m", "pytest", "-x", "-q",
          os.path.abspath(__file__), "-k", "mesh and not subprocess"],

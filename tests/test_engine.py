@@ -266,3 +266,26 @@ def test_engine_rejects_silent_vote_noops():
     with pytest.raises(ValueError, match="serial"):
         GenerationEngine(cfg, Tmr("serial"), gen=4, vote_every=2)
     GenerationEngine(cfg, Tmr("serial"), gen=4)          # vote-at-end: fine
+
+
+@pytest.fixture
+def donating(monkeypatch):
+    """Donation on the CPU too, so a read of a donated buffer fails here
+    as it would on an accelerator."""
+    import repro.launch.engine as engine_mod
+    monkeypatch.setattr(engine_mod, "DONATE_ON_CPU", True)
+
+
+@pytest.mark.parametrize("spec", ["ecc", "ecc+tmr-parallel"])
+def test_prepare_donates_params_only_when_asked(donating, spec):
+    """`prepare` leaves every params leaf alive unless the caller passes
+    donate=True, and the store does not depend on it."""
+    cfg, key, params, _ = _setup("dense")
+    engine = GenerationEngine(cfg, parse_scheme(spec), gen=GEN)
+    fault = TransientBitFlips(1e-3)
+    kept, _ = engine.prepare(params, key=key, fault=fault)
+    assert not any(x.is_deleted() for x in jax.tree.leaves(params))
+    taken, _ = engine.prepare(params, key=key, fault=fault, donate=True)
+    assert all(x.is_deleted() for x in jax.tree.leaves(params))
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(taken)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -250,3 +250,34 @@ def test_train_loop_permanent_faults_use_stable_key(key):
     # corrupting the already-corrupted params is a no-op (idempotent defects)
     c3 = loop._corrupt(c1)
     assert (np.asarray(c3["w"]) == np.asarray(c1["w"])).all()
+
+
+# --- row-offset draws (slab-wise corruption of large leaves) -----------------
+
+@pytest.mark.parametrize("offset", [0, 37, 4096])
+def test_uniform_row_offset_matches_whole_draw(key, offset):
+    """Rows offset.. of one large draw, made on their own, are bit-equal to
+    the same rows of the whole draw."""
+    from repro.faults.models import uniform
+    whole = jax.random.uniform(key, (5000, 32))
+    part = jax.jit(uniform, static_argnums=1)(key, (512, 32),
+                                              jnp.int32(offset))
+    np.testing.assert_array_equal(np.asarray(part),
+                                  np.asarray(whole[offset:offset + 512]))
+
+
+@pytest.mark.parametrize("model", [
+    TransientBitFlips(2e-3), RetentionDrift(2e-3), StuckAtFaults(1e-3, 1e-3),
+    CompositeFault((TransientBitFlips(1e-3), StuckAtFaults(1e-3, 0.0))),
+], ids=lambda m: type(m).__name__)
+def test_slab_word_masks_match_whole_buffer(model, key):
+    """A buffer corrupted slab by slab (word offsets) is corrupted exactly
+    as in one call — the contract the per-leaf serving prepare relies
+    on."""
+    words = jax.random.bits(key, (3 * 1024,), jnp.uint32)
+    whole = model.corrupt_words(words, key)
+    slabs = [model.corrupt_words(words[i:i + 1024], key, offset=i)
+             for i in range(0, words.shape[0], 1024)]
+    np.testing.assert_array_equal(np.asarray(jnp.concatenate(slabs)),
+                                  np.asarray(whole))
+    assert int((whole != words).sum()) > 0

@@ -125,7 +125,6 @@ def test_registry_accumulate_under_jit_and_vmap():
 def test_registry_psum_matches_single_device():
     """Counters accumulated per shard and psum'd inside shard_map equal
     the single-device totals bit for bit (DESIGN.md §14)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec
 
     reg = DEFAULT_REGISTRY
@@ -138,9 +137,9 @@ def test_registry_psum_matches_single_device():
                             "faults_injected": (x * 2).sum()})
         return reg.psum(m, "data")
 
-    sharded = shard_map(body, mesh=mesh,
-                        in_specs=PartitionSpec("data"),
-                        out_specs=PartitionSpec())(xs)
+    sharded = jax.shard_map(body, mesh=mesh,
+                            in_specs=PartitionSpec("data"),
+                            out_specs=PartitionSpec())(xs)
     single = reg.accumulate(reg.zeros(["ecc_corrected", "faults_injected"]),
                             {"ecc_corrected": xs.sum(),
                              "faults_injected": (xs * 2).sum()})
@@ -158,7 +157,7 @@ def test_psum_subprocess():
                                        "src"))
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
-               PYTHONPATH=src)
+               JAX_PLATFORMS="cpu", PYTHONPATH=src)
     out = subprocess.run(
         [sys.executable, "-m", "pytest", "-x", "-q",
          "-k", "psum_matches_single_device", os.path.abspath(__file__)],

@@ -1,9 +1,10 @@
 """Sharded generation engine (DESIGN.md §14): bit-exactness against the
 single-device engine on forced-host-device meshes.
 
-The native tests need >= 4 devices and skip otherwise; on single-device
-hosts the slow wrapper test re-invokes this file in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=4`` (jax locks the
+The native tests need >= 4 devices (6 for copy groups of two) and skip
+otherwise; on single-device hosts the slow wrapper test re-invokes this
+file in a subprocess with
+``XLA_FLAGS=--xla_force_host_platform_device_count=6`` (jax locks the
 device count at first init, so the flag cannot be set in-process).  The CI
 sharded smoke job sets the flag and runs the native tests directly.
 """
@@ -120,6 +121,63 @@ def test_fold_copy_axis_and_exec_mesh():
 
 
 @needs_devices
+def test_copies_built_on_their_own_devices(setup):
+    """On a copy-folded 3x1 mesh each TMR copy is built on its own device:
+    every shard of copy c lives on copy group c, so no device ever holds
+    two copies — and tokens and counters equal the single-device run."""
+    cfg, key, params, batch = setup
+    scheme = parse_scheme("ecc+tmr-parallel")
+    fault = TransientBitFlips(P_BIT)
+    ref = GenerationEngine(cfg, scheme, gen=GEN)
+    store, prep = ref.prepare(params, key=key, fault=fault)
+    ref_toks, ref_tel = ref.generate(store, batch)
+    ref_stats = fetch_telemetry({**prep, **ref_tel})
+
+    eng = GenerationEngine(cfg, scheme, gen=GEN, mesh=make_test_mesh(3, 1))
+    store, prep = eng.prepare(params, key=key, fault=fault)
+    homes = eng._copy_devices()
+    assert len(set(homes)) == 3
+    for leaf in jax.tree.leaves(store):
+        for shard in leaf.addressable_shards:
+            assert shard.device == homes[shard.index[0].start or 0]
+    toks, tel = eng.generate(store, batch)
+    np.testing.assert_array_equal(np.asarray(toks), np.asarray(ref_toks))
+    got = fetch_telemetry({**prep, **tel})
+    assert int(got["ecc_corrected"]) > 0
+    for k in ref_stats:
+        np.testing.assert_array_equal(got[k], ref_stats[k], err_msg=k)
+
+
+@pytest.mark.skipif(jax.device_count() < 6, reason="needs >= 6 devices")
+@pytest.mark.parametrize("mesh_shape", [(6, 1), (3, 2)],
+                         ids=lambda s: f"{s[0]}x{s[1]}")
+@pytest.mark.parametrize("spec", ["tmr-parallel", "tmr-semi"])
+def test_replicas_on_multi_device_copy_groups(setup, references, spec,
+                                              mesh_shape):
+    """Where each copy group holds two devices, each copy runs as the
+    single-copy program sharded over its own group, whole or chunked:
+    tokens and vote counters equal the single-device run."""
+    cfg, key, params, batch = setup
+    eng = GenerationEngine(cfg, parse_scheme(spec), gen=GEN,
+                           mesh=make_test_mesh(*mesh_shape))
+    groups = eng._copy_meshes()
+    assert eng._replicated and [m.size for m in groups] == [2, 2, 2]
+    store, _ = eng.prepare(params, key=key, fault=TransientBitFlips(P_BIT))
+    for c, part in enumerate(eng._replicas(store)):
+        for leaf in jax.tree.leaves(part):
+            assert leaf.sharding.mesh.devices.tolist() == \
+                groups[c].devices.tolist()
+    ref_toks, ref_tel = references[eng.scheme.name]
+    toks, tel = eng.generate(store, batch)
+    chunked, tel_c, _ = eng.generate_chunked(store, batch, chunk=1)
+    for t, got in ((toks, fetch_telemetry(tel)),
+                   (chunked, fetch_telemetry(tel_c))):
+        np.testing.assert_array_equal(np.asarray(t), ref_toks)
+        for k in ref_tel:
+            np.testing.assert_array_equal(got[k], ref_tel[k], err_msg=k)
+
+
+@needs_devices
 def test_protected_device_put_roundtrip(setup):
     """Protected stores round-trip through jax.device_put with the
     scheme-aware sharded PartitionSpecs: same bits, same scrub reports."""
@@ -147,21 +205,22 @@ def test_protected_device_put_roundtrip(setup):
 
 @needs_devices
 def test_canonical_parts_mixed_shardings():
-    """jax 0.4.x concatenates eager arrays with MIXED shardings wrong on
-    multi-device (an unreduced cross-replica sum doubles every value);
-    `arena.canonical_parts` is the guard `pack`/`scrub_copies` rely on."""
+    """Eager concatenation of arrays with MIXED shardings is exact — jax
+    0.4.37 doubled every value per replicated mesh axis, which once needed
+    a canonicalizing guard; `arena.pack` and `scrub_copies` concatenate
+    such parts directly."""
     from jax.sharding import NamedSharding, PartitionSpec
-    from repro.core.arena import canonical_parts
     mesh = make_test_mesh(2, 2)
     a = jnp.arange(8, dtype=jnp.uint32)
     b = jnp.arange(100, 108, dtype=jnp.uint32)
-    aa = jax.device_put(a, NamedSharding(mesh, PartitionSpec("data")))
-    bb = jax.device_put(b, NamedSharding(mesh, PartitionSpec(None)))
-    got = jnp.concatenate(canonical_parts([aa, bb]))
-    np.testing.assert_array_equal(
-        np.asarray(got), np.concatenate([np.arange(8, dtype=np.uint32),
-                                         np.arange(100, 108,
-                                                   dtype=np.uint32)]))
+    want = np.concatenate([np.arange(8, dtype=np.uint32),
+                           np.arange(100, 108, dtype=np.uint32)])
+    for sa, sb in [(("data",), (None,)), (("data",), ("model",)),
+                   ((None,), ("data",))]:
+        aa = jax.device_put(a, NamedSharding(mesh, PartitionSpec(*sa)))
+        bb = jax.device_put(b, NamedSharding(mesh, PartitionSpec(*sb)))
+        np.testing.assert_array_equal(np.asarray(jnp.concatenate([aa, bb])),
+                                      want)
 
 
 @needs_devices
@@ -203,13 +262,14 @@ def test_sharded_scrub_ops_match():
 @pytest.mark.skipif(MULTI, reason="already running with >= 4 devices")
 def test_sharded_suite_subprocess():
     """Single-device hosts: run this file's native tests in a subprocess
-    with 4 forced host devices, so tier-1 covers the sharded engine
-    everywhere (the CI sharded job runs them natively)."""
+    with 6 forced host devices (the 4-device meshes take the first four),
+    so tier-1 covers the sharded engine everywhere (the CI sharded job
+    runs them natively)."""
     src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
                                        "src"))
     env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=4",
-               PYTHONPATH=src)
+               XLA_FLAGS="--xla_force_host_platform_device_count=6",
+               JAX_PLATFORMS="cpu", PYTHONPATH=src)
     out = subprocess.run(
         [sys.executable, "-m", "pytest", "-x", "-q",
          os.path.abspath(__file__)],

@@ -97,7 +97,8 @@ from repro.optim import AdamWConfig
 from repro.pshard import DEFAULT_RULES, use_mesh_and_rules
 from repro.models.params import abstractify
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_config("qwen2.5-14b").smoke().replace(d_model=64, d_ff=128, vocab=256)
 with use_mesh_and_rules(mesh, DEFAULT_RULES):
     specs = T.model_specs(cfg)
@@ -113,5 +114,6 @@ with use_mesh_and_rules(mesh, DEFAULT_RULES):
 """
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run([sys.executable, "-c", script % os.path.abspath(src)],
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
                          capture_output=True, text=True, timeout=600)
     assert "COMPILED True" in out.stdout, out.stderr[-2000:]
