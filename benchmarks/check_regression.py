@@ -2,13 +2,12 @@
 the committed `benchmarks/baseline.json`.
 
 Rows from the guarded modules (netlist_bench, campaign_mc, serve_bench,
-serve_load, obs_overhead, mmpu_cost) are compared by name on their
+serve_load, mmpu_cost, ecc_frontier) are compared by name on their
 throughput signals:
 
 * ratio signals from `derived` (``speedup_vs_scan=`` for the netlist
   engines, ``speedup_vs_loop=`` / ``tmr_amortization=`` for the serving
-  engine, ``goodput_gain=`` for the continuous-batching scheduler,
-  ``telemetry_efficiency=`` for the observability overhead) are
+  engine, ``goodput_gain=`` for the continuous-batching scheduler) are
   machine-INDEPENDENT and compared directly — they catch
   engine-relative regressions regardless of how fast the CI runner is;
 * model signals (``cycles_per_token=`` / ``energy_pj_per_token=`` from
@@ -42,13 +41,13 @@ import sys
 from typing import Dict, Tuple
 
 GUARDED_MODULES = ("netlist_bench", "campaign_mc", "serve_bench",
-                   "serve_load", "obs_overhead", "mmpu_cost",
+                   "serve_load", "mmpu_cost",
                    "ecc_frontier")
 DEFAULT_BASELINE = os.path.join(os.path.dirname(__file__), "baseline.json")
 _RATE_RE = re.compile(r"(gate_evals_per_s|tok_s)=([0-9.eE+-]+)")
 _RATIO_RE = re.compile(
     r"(speedup_vs_scan|speedup_vs_loop|tmr_amortization"
-    r"|goodput_gain|telemetry_efficiency|adaptive_speedup)=([0-9.eE+-]+)x")
+    r"|goodput_gain|adaptive_speedup)=([0-9.eE+-]+)x")
 # mMPU cost-model projections (benchmarks.mmpu_cost): machine-INDEPENDENT
 # analytic numbers — pure shape arithmetic, identical on any runner — so
 # they are compared directly (no machine normalization) and lower is

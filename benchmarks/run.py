@@ -25,7 +25,7 @@ except ImportError:       # direct script execution
 
 MODULES = ["fig4_mult", "fig4_nn", "fig5_weights", "ecc_overhead",
            "tmr_tradeoff", "kernels_bench", "campaign_mc", "netlist_bench",
-           "serve_bench", "serve_load", "obs_overhead", "mmpu_cost",
+           "serve_bench", "serve_load", "mmpu_cost",
            "ecc_frontier"]
 
 

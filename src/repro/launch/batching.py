@@ -74,7 +74,8 @@ import numpy as np
 from ..core import arena
 from ..models.config import ModelConfig
 from ..models.steps import make_decode_step, make_prefill_step
-from ..obs import DEFAULT_REGISTRY, LatencyTimeline, MetricsRegistry
+from ..obs import (DEFAULT_REGISTRY, RECORDER, LatencyTimeline,
+                   MetricsRegistry, Phased, Tracer)
 from ..pshard import use_mesh_and_rules
 from ..reliability.backend import dispatch as _backend
 from ..reliability.scheme import ArenaEcc, Compose, Scheme
@@ -82,6 +83,12 @@ from .engine import GenerationEngine, donate_argnums
 
 __all__ = ["BatchSpec", "Request", "RequestResult", "PagedKVPool",
            "ContinuousBatcher", "poisson_trace", "sequential_slot_steps"]
+
+
+#: named scopes of the tick and admission programs: the phases a device
+#: trace of them splits into (`obs.phase_of`)
+TICK_PHASES = ("repair", "gather", "step", "scatter", "write_out", "refresh")
+ADMIT_PHASES = ("prefill", "place", "refresh")
 
 
 def _ceil_div(a: int, b: int) -> int:
@@ -261,9 +268,10 @@ class PagedKVPool:
             ecc, aspec = self.ecc, self.arena_spec
 
             def run(k, v, parity):
-                fixed, par2, counts = ecc.scrub_arena(
-                    arena.pack({"k": k, "v": v})[0], parity)
-                kv = arena.unpack(fixed, aspec)
+                with jax.named_scope("scrub"):
+                    fixed, par2, counts = ecc.scrub_arena(
+                        arena.pack({"k": k, "v": v})[0], parity)
+                    kv = arena.unpack(fixed, aspec)
                 return kv["k"], kv["v"], par2, counts
 
             self._scrub_fn = jax.jit(run)
@@ -345,7 +353,8 @@ class ContinuousBatcher:
                  spec: BatchSpec = BatchSpec(), *, mesh=None, rules=None,
                  scrub_every: int = 0, adaptive=None,
                  forced_scrub_ticks: Optional[Sequence[int]] = None,
-                 registry: MetricsRegistry = DEFAULT_REGISTRY):
+                 registry: MetricsRegistry = DEFAULT_REGISTRY,
+                 tracer: Tracer = RECORDER):
         if cfg.family not in ("dense", "moe"):
             raise ValueError(
                 f"continuous batching supports dense/moe decode caches; "
@@ -396,6 +405,10 @@ class ContinuousBatcher:
         #: ``b.on_tick = lambda b: b.pool.corrupt(next_key(), fault)``)
         self.on_tick = None
         self._registry = registry
+        #: host spans and counters of every tick, admission and request
+        #: (the process-wide flight recorder unless given another)
+        self.tracer = tracer
+        self._admissions = 0
         self._wb = self.ecc is not None and self.ecc.write_back
         self._telem = registry.zeros(
             ["ecc_corrected", "ecc_parity_fixed", "ecc_uncorrectable",
@@ -513,20 +526,24 @@ class ContinuousBatcher:
         wb = self.ecc is not None and self.ecc.write_back
 
         def one(params, tok, pk, pv, pos, table):
-            cache = {"pos": pos, "k": self._gather(pk, table),
-                     "v": self._gather(pv, table)}
+            with jax.named_scope("gather"):
+                cache = {"pos": pos, "k": self._gather(pk, table),
+                         "v": self._gather(pv, table)}
 
             def body(carry, _):
                 tok, cache = carry
                 ntok, _, cache = decode(params, tok, cache)
                 return (ntok, cache), ntok
 
-            (tok, cache), toks = jax.lax.scan(body, (tok, cache), None,
-                                              length=chunk)
-            pk = self._scatter(pk, table, cache["k"])
-            pv = self._scatter(pv, table, cache["v"])
-            # toks (chunk, S, 1) -> (S, chunk)
-            return tok, pk, pv, cache["pos"], toks[:, :, 0].T
+            with jax.named_scope("step"):
+                (tok, cache), toks = jax.lax.scan(body, (tok, cache), None,
+                                                  length=chunk)
+            with jax.named_scope("scatter"):
+                pk = self._scatter(pk, table, cache["k"])
+                pv = self._scatter(pv, table, cache["v"])
+            with jax.named_scope("write_out"):
+                toks = toks[:, :, 0].T       # (chunk, S, 1) -> (S, chunk)
+            return tok, pk, pv, cache["pos"], toks
 
         def write_out(ob, tk, off):
             return jax.lax.dynamic_update_slice(ob, tk, (off,))
@@ -550,8 +567,9 @@ class ContinuousBatcher:
                 # correct-on-read: the tick reads every table page through
                 # the gather, so repair all of them first — in the SAME
                 # launch, before the decode sees a single bit
-                pk, pv, parity, rcounts = self._correct_pages(
-                    pk, pv, parity, table.reshape(-1))
+                with jax.named_scope("repair"):
+                    pk, pv, parity, rcounts = self._correct_pages(
+                        pk, pv, parity, table.reshape(-1))
             else:
                 rcounts = jnp.zeros((3,), jnp.int32)
             if copy:
@@ -565,17 +583,21 @@ class ContinuousBatcher:
                     tok, pk, pv, pos3, toks = jax.vmap(f)(
                         (store, tok, pk, pv))
                 pos = pos3[0]
-                out = jax.vmap(jax.vmap(write_out),
-                               in_axes=(0, 0, None))(out, toks, off)
+                with jax.named_scope("write_out"):
+                    out = jax.vmap(jax.vmap(write_out),
+                                   in_axes=(0, 0, None))(out, toks, off)
             else:
                 tok, pk, pv, pos, toks = one(store, tok, pk, pv, pos, table)
-                out = jax.vmap(write_out)(out, toks, off)
-            par = self._refresh_parity(pk, pv, parity,
-                                       touched(table, pos - chunk))
+                with jax.named_scope("write_out"):
+                    out = jax.vmap(write_out)(out, toks, off)
+            with jax.named_scope("refresh"):
+                par = self._refresh_parity(pk, pv, parity,
+                                           touched(table, pos - chunk))
             return tok, out, pk, pv, pos, par, rcounts
 
         donate = donate_argnums(1, 2, 3, 4, 5, 6)
-        self._tick_fn = jax.jit(tick, donate_argnums=donate)
+        self._tick_fn = Phased(jax.jit(tick, donate_argnums=donate),
+                               TICK_PHASES)
         return self._tick_fn
 
     def _admit_program(self, plen: int):
@@ -596,29 +618,31 @@ class ContinuousBatcher:
                   slot):
             def one(args):
                 params, k, v = args
-                t0, _, cache = prefill(params, {"tokens": tokens})
-                return (t0[0, 0], place(k, table_row, cache["k"]),
-                        place(v, table_row, cache["v"]))
+                with jax.named_scope("prefill"):
+                    t0, _, cache = prefill(params, {"tokens": tokens})
+                with jax.named_scope("place"):
+                    return (t0[0, 0], place(k, table_row, cache["k"]),
+                            place(v, table_row, cache["v"]))
 
             if copy:
                 if serial:
                     t0, pk, pv = jax.lax.map(one, (store, pk, pv))
                 else:
                     t0, pk, pv = jax.vmap(one)((store, pk, pv))
-                tok = tok.at[:, slot, 0].set(t0)
-                out = out.at[:, slot, 0].set(t0)
             else:
                 t0, pk, pv = one((store, pk, pv))
-                tok = tok.at[slot, 0].set(t0)
-                out = out.at[slot, 0].set(t0)
-            pos = pos.at[slot].set(plen)
+            with jax.named_scope("place"):
+                tok = tok.at[..., slot, 0].set(t0)
+                out = out.at[..., slot, 0].set(t0)
+                pos = pos.at[slot].set(plen)
             # place() rewrote the slot's whole table row (scratch included
             # for unreserved entries) — refresh exactly those pages
-            par = self._refresh_parity(pk, pv, parity, table_row)
+            with jax.named_scope("refresh"):
+                par = self._refresh_parity(pk, pv, parity, table_row)
             return tok, out, pk, pv, pos, par
 
         donate = donate_argnums(1, 2, 3, 4, 5, 6)
-        fn = jax.jit(admit, donate_argnums=donate)
+        fn = Phased(jax.jit(admit, donate_argnums=donate), ADMIT_PHASES)
         self._admit_fns[plen] = fn
         return fn
 
@@ -674,42 +698,87 @@ class ContinuousBatcher:
         return n
 
     def _admit_one(self, req, tl, slot, pages):
-        row = np.zeros(self.spec.max_pages, np.int32)
-        row[:len(pages)] = pages
-        self.table[slot] = row
-        fn = self._admit_program(len(req.prompt))
-        tokens = jnp.asarray(np.asarray(req.prompt, np.int32)[None, :])
-        with use_mesh_and_rules(self.engine.exec_mesh, self.engine.rules):
-            (self._tok, self._out, self.pool.k, self.pool.v, self._pos,
-             self.pool.parity) = fn(
-                self.store, self._tok, self._out, self.pool.k, self.pool.v,
-                self._pos, self.pool.parity, tokens, jnp.asarray(row),
-                jnp.int32(slot))
-        jax.block_until_ready(self._tok)     # sync point, no data transfer
-        tl.mark(1)                           # <- TTFT
-        self._slots[slot] = _Active(req=req, pages=pages, emitted=1,
-                                    timeline=tl)
+        tr, rid = self.tracer, req.rid
+        with tr.trace("batcher.admit", rid=rid):
+            with tr.trace("admit.launch", rid=rid):
+                row = np.zeros(self.spec.max_pages, np.int32)
+                row[:len(pages)] = pages
+                self.table[slot] = row
+                fn = self._admit_program(len(req.prompt))
+                tokens = jnp.asarray(np.asarray(req.prompt,
+                                                np.int32)[None, :])
+                with use_mesh_and_rules(self.engine.exec_mesh,
+                                        self.engine.rules):
+                    (self._tok, self._out, self.pool.k, self.pool.v,
+                     self._pos, self.pool.parity) = fn(
+                        self.store, self._tok, self._out, self.pool.k,
+                        self.pool.v, self._pos, self.pool.parity, tokens,
+                        jnp.asarray(row), jnp.int32(slot))
+            with tr.trace("admit.wait", rid=rid):
+                jax.block_until_ready(self._tok)  # sync, no data transfer
+            tl.mark(1)                            # <- TTFT
+            self._slots[slot] = _Active(req=req, pages=pages, emitted=1,
+                                        timeline=tl)
+        self._admissions += 1
+        tr.counter("batcher.admissions", self._admissions)
 
     def tick(self) -> List[RequestResult]:
         """One scheduler tick: `chunk` decode steps for every slot in one
         launch (per copy when serial), then host-side completion
         bookkeeping.  The ONLY device->host transfer is one batched
         `device_get` of finished rows, and only on ticks where a request
-        finishes."""
+        finishes.  Its host path is recorded as a `batcher.tick` span
+        with one child per part: `tick.launch` (page table and offsets
+        up, dispatch), `tick.wait`, `tick.finish` (finished rows down,
+        vote, free), `tick.scrub` and `tick.scrub_fetch`.  The counter
+        tracks `batcher.scrubs` and `batcher.admissions` (running totals)
+        step at each pool scrub and admission."""
+        spec, tr = self.spec, self.tracer
+        with tr.trace("batcher.tick"):
+            if self.on_tick is not None:
+                self.on_tick(self)   # pre-launch hook (fault injection)
+            active = [(i, a) for i, a in enumerate(self._slots)
+                      if a is not None]
+            off = np.zeros(spec.slots, np.int32)
+            for i, a in active:
+                off[i] = a.emitted
+            with tr.trace("tick.launch"):
+                with use_mesh_and_rules(self.engine.exec_mesh,
+                                        self.engine.rules):
+                    (self._tok, self._out, self.pool.k, self.pool.v,
+                     self._pos, self.pool.parity, rcounts) = \
+                        self._tick_program()(
+                            self.store, self._tok, self._out, self.pool.k,
+                            self.pool.v, self._pos, self.pool.parity,
+                            jnp.asarray(self.table), jnp.asarray(off))
+            with tr.trace("tick.wait"):
+                jax.block_until_ready(self._tok)
+            with tr.trace("tick.finish"):
+                finished = self._complete(active, rcounts)
+            if self.ecc is not None and self._scrub_due():
+                with tr.trace("tick.scrub"):
+                    counts = self.pool.scrub()   # counters stay on device
+                    self.scrub_ticks.append(self.ticks)
+                    self._telem = self._registry.accumulate(
+                        self._telem, {"ecc_corrected": counts[0],
+                                      "ecc_parity_fixed": counts[1],
+                                      "ecc_uncorrectable": counts[2]})
+                if self.adaptive is not None and self._forced_scrub is None:
+                    # the documented zero-sync exception: the controller
+                    # needs the counts on host to reschedule; one (4,)-int
+                    # fetch per scrub, and scrubs get RARER as the
+                    # controller backs off
+                    with tr.trace("tick.scrub_fetch"):
+                        c = np.asarray(jax.device_get(counts))
+                        self.adaptive.record(self.ticks, int(c[0]),
+                                             int(c[2]), int(c[1]))
+                tr.counter("batcher.scrubs", len(self.scrub_ticks))
+        return finished
+
+    def _complete(self, active, rcounts) -> List[RequestResult]:
+        """Host-side completion after a tick: token marks, then ONE
+        batched fetch of every finished row, vote and free."""
         spec = self.spec
-        if self.on_tick is not None:
-            self.on_tick(self)       # pre-launch hook (fault injection)
-        active = [(i, a) for i, a in enumerate(self._slots) if a is not None]
-        off = np.zeros(spec.slots, np.int32)
-        for i, a in active:
-            off[i] = a.emitted
-        with use_mesh_and_rules(self.engine.exec_mesh, self.engine.rules):
-            (self._tok, self._out, self.pool.k, self.pool.v, self._pos,
-             self.pool.parity, rcounts) = self._tick_program()(
-                self.store, self._tok, self._out, self.pool.k, self.pool.v,
-                self._pos, self.pool.parity, jnp.asarray(self.table),
-                jnp.asarray(off))
-        jax.block_until_ready(self._tok)
         if self._wb:
             # read-path repairs land in their own counters (on device)
             self._telem = self._registry.accumulate(
@@ -732,20 +801,6 @@ class ContinuousBatcher:
             rows = jax.device_get([self._out[..., i, :] for i, _ in done])
             for (i, a), row in zip(done, rows):
                 finished.append(self._finish(i, a, np.asarray(row)))
-        if self.ecc is not None and self._scrub_due():
-            counts = self.pool.scrub()       # counters stay on device
-            self.scrub_ticks.append(self.ticks)
-            if self.adaptive is not None and self._forced_scrub is None:
-                # the documented zero-sync exception: the controller needs
-                # the counts on host to reschedule; one (4,)-int fetch per
-                # scrub, and scrubs get RARER as the controller backs off
-                c = np.asarray(jax.device_get(counts))
-                self.adaptive.record(self.ticks, int(c[0]), int(c[2]),
-                                     int(c[1]))
-            self._telem = self._registry.accumulate(
-                self._telem, {"ecc_corrected": counts[0],
-                              "ecc_parity_fixed": counts[1],
-                              "ecc_uncorrectable": counts[2]})
         return finished
 
     def _scrub_due(self) -> bool:
@@ -767,10 +822,13 @@ class ContinuousBatcher:
             dis = int(np.sum(~((t[0] == t[1]) & (t[0] == t[2]))))
         else:
             tokens, dis = row[:gen].astype(np.int32), 0
-        res = RequestResult(rid=a.req.rid, tokens=tokens,
-                            ttft_s=a.timeline.ttft_s,
-                            tpot_samples=list(a.timeline.tpot_samples()),
-                            vote_disagreements=dis, timeline=a.timeline)
+        tl = a.timeline
+        res = RequestResult(rid=a.req.rid, tokens=tokens, ttft_s=tl.ttft_s,
+                            tpot_samples=list(tl.tpot_samples()),
+                            vote_disagreements=dis, timeline=tl)
+        # submit -> last token, from the timeline's own clock reads
+        self.tracer.add_span("request", tl.start * 1e9, tl.marks[-1][0] * 1e9,
+                             rid=a.req.rid, tokens=gen)
         self.results[a.req.rid] = res
         self._tokens_emitted += gen
         self._vote_disagreements += dis

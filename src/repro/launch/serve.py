@@ -54,7 +54,10 @@ TTFT (queue wait included) and TPOT flow through LatencyTimeline, and the
 report gives p50/p95/p99 tails plus goodput (useful tokens / wall time).
 ``--gen`` becomes the per-request generation cap, ``--chunk`` the decode
 chunk between scheduling points (default 8), ``--prompt-len`` the single
-admission bucket.
+admission bucket.  With ``--trace`` the Chrome trace carries the
+scheduler's own spans (`batcher.tick` and its parts, `batcher.admit`,
+one `request` span per request), and the ``kind=server`` metrics record
+its five slowest ticks, split by child span.
 """
 from __future__ import annotations
 
@@ -71,7 +74,7 @@ from ..faults import (FaultModel, RetentionDrift, StuckAtFaults,
                       TransientBitFlips)
 from ..models import params as P
 from ..models import transformer as T
-from ..obs import LatencyTimeline, Tracer
+from ..obs import RECORDER, LatencyTimeline, Tracer
 from ..reliability import Compose, Tmr, parse_scheme, scheme_choices, \
     scheme_help
 from .batching import BatchSpec, ContinuousBatcher, Request, poisson_trace
@@ -89,9 +92,11 @@ def _run_server(args, cfg, key, params, scheme, fault, mesh
     spec = BatchSpec(slots=args.slots, page_tokens=args.page_tokens,
                      chunk=chunk, prompt_buckets=(args.prompt_len,),
                      gen_cap=args.gen)
-    tracer = Tracer(enabled=bool(args.trace or args.metrics))
+    # the batcher's spans go to the process's flight recorder, or to a
+    # tracer of this run's own where they are written out
+    tracer = Tracer() if (args.trace or args.metrics) else RECORDER
     b = ContinuousBatcher(cfg, scheme, spec, mesh=mesh,
-                          scrub_every=args.scrub_every)
+                          scrub_every=args.scrub_every, tracer=tracer)
     if getattr(args, "adaptive_scrub", False) and b.ecc is not None:
         from ..runtime import AdaptiveScrub
         # prior sized for the POOL the controller actually scrubs
@@ -115,7 +120,7 @@ def _run_server(args, cfg, key, params, scheme, fault, mesh
         b.run(warm)
     t_warm = time.time() - t_warm
 
-    t0 = time.time()
+    t0, serve_ns = time.time(), time.perf_counter_ns()
     with tracer.trace("serve", requests=args.requests, rate=args.rate,
                       scheme=scheme.name):
         results = b.run(trace, realtime=True)
@@ -160,6 +165,9 @@ def _run_server(args, cfg, key, params, scheme, fault, mesh
                   "ttft_p99_s": q(ttft, 99),
                   "tpot_p50_s": q(tpot, 50), "tpot_p95_s": q(tpot, 95),
                   "tpot_p99_s": q(tpot, 99),
+                  # the recorder's five slowest ticks, by child span
+                  "slowest_ticks": tracer.slowest("batcher.tick", 5,
+                                                  serve_ns),
                   **{k: (np.asarray(v).sum().item()
                          if hasattr(v, "shape") else v)
                      for k, v in stats.items()}}

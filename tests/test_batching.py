@@ -127,6 +127,176 @@ def test_tick_single_transfer_contract(setup):
 
 # -- goodput: continuous batching vs whole-batch serving ---------------------
 
+# -- device phases and the flight recorder -----------------------------------
+
+PHASE_SPEC = BatchSpec(slots=2, page_tokens=16, chunk=4,
+                       prompt_buckets=(16,), gen_cap=8)
+
+
+@pytest.fixture(scope="module")
+def smoke_served():
+    """`.smoke()` widths under hsiao-wb and off: each scheme's batcher
+    after serving two requests, and their tokens."""
+    cfg = get_config("phi3-mini-3.8b").smoke()
+    key = jax.random.PRNGKey(0)
+    params = P.materialize(key, T.model_specs(cfg), dtype=cfg.cdtype)
+    rng = np.random.default_rng(0)
+    reqs = [Request(i, rng.integers(0, cfg.vocab, 16, dtype=np.int32), g)
+            for i, g in enumerate((8, 5))]
+    out = {}
+    for name in ("hsiao-wb", "off"):
+        b = ContinuousBatcher(cfg, parse_scheme(name), PHASE_SPEC)
+        b.prepare(params, key=key)
+        out[name] = (b, [r.tokens for r in b.run(reqs)])
+    return cfg, key, params, reqs, out
+
+
+def _mapped(program):
+    """Phases of the program's instructions that do work: parameters,
+    constants, tuples and loop control left out."""
+    from repro.obs.phases import STRUCTURAL
+    return [p for n, (p, *_) in program.phase_map.items()
+            if program.opcodes[n] not in STRUCTURAL
+            and program.opcodes[n] != "loop-control"]
+
+
+def test_the_tick_is_split_into_its_phases(smoke_served):
+    *_, out = smoke_served
+    wb, off = out["hsiao-wb"][0]._tick_program(), out["off"][0]._tick_program()
+    assert wb.program == off.program == "tick"
+    assert {"repair", "gather", "step", "scatter", "refresh"} <= \
+        set(_mapped(wb))
+    assert {"gather", "step", "scatter"} <= set(_mapped(off))
+    assert not {"repair", "refresh"} & set(_mapped(off))
+    for prog in (wb, off):
+        phases = _mapped(prog)
+        assert sum(p is not None for p in phases) >= 0.95 * len(phases)
+        # a phase is inherited only from an instruction that owns it
+        entries = [e for n, e in prog.phase_map.items()
+                   if e[0] is not None and prog.opcodes[n] != "loop-control"]
+        assert any(inh for _, _, inh in entries)
+        assert {p for p, _, inh in entries if not inh} == \
+            {p for p, *_ in entries}
+
+
+def test_admission_phases_and_lookup_by_instruction_text(smoke_served,
+                                                         monkeypatch):
+    from repro import obs
+    from repro.obs import phases as PH
+    *_, out = smoke_served
+    b = out["hsiao-wb"][0]
+    admit = b._admit_program(16)
+    assert admit.program == "admit"
+    assert {"prefill", "place", "refresh"} <= set(_mapped(admit))
+    # the lookup takes an op's instruction text, as a trace names it;
+    # a registry holding this program alone
+    tick = b._tick_program()
+    monkeypatch.setattr(PH, "_MAPS", {})
+    PH.register_phases(tick.compiled.as_text(), tick.phases)
+    texts = {}
+    for ln in tick.compiled.as_text().splitlines():
+        ln = ln.strip().removeprefix("ROOT ")
+        if ln.startswith("%") and " = " in ln:
+            texts[ln[1:ln.index(" = ")]] = ln.split(", metadata=")[0]
+    hits = 0
+    for name, (phase, *_) in tick.phase_map.items():
+        if phase is not None:
+            assert obs.phase_of(texts[name], "tick") == phase
+            hits += 1
+    assert hits > 10
+    assert obs.phase_of("%no_such_instruction.1 = f32[2]{0} add()",
+                        "tick") is None
+    assert obs.phase_of("not an instruction") is None
+    # where two programs of one name disagree on an instruction of the
+    # same result type, the lookup refuses to guess
+    off = out["off"][0]._tick_program()
+    PH.register_phases(off.compiled.as_text(), off.phases)
+    disagree = [n for n, (p, sig, _) in tick.phase_map.items()
+                if off.phase_map.get(n, (p, None))[1] == sig
+                and off.phase_map[n][0] != p]
+    assert disagree
+    for n in disagree:
+        assert obs.phase_of(texts[n], "tick") is None
+
+
+def test_tokens_are_bit_exact_with_and_without_the_recorder(smoke_served):
+    from repro.obs import NULL_TRACER, RECORDER
+    cfg, key, params, reqs, out = smoke_served
+    b, tokens = out["hsiao-wb"]
+    assert b.tracer is RECORDER
+    quiet = ContinuousBatcher(cfg, parse_scheme("hsiao-wb"), PHASE_SPEC,
+                              tracer=NULL_TRACER)
+    quiet.prepare(params, key=key)
+    for a, c in zip(tokens, [r.tokens for r in quiet.run(reqs)]):
+        np.testing.assert_array_equal(a, c)
+    assert NULL_TRACER.events == []
+
+
+def test_the_recorder_sees_ticks_admissions_and_requests(setup):
+    from repro.obs import Tracer
+    cfg, key, params, prompts = setup
+    tracer = Tracer()
+    b = ContinuousBatcher(cfg, parse_scheme("ecc"), SPEC, scrub_every=1,
+                          tracer=tracer)
+    b.prepare(params, key=key)
+    b.run([Request(0, prompts[8], 5), Request(1, prompts[4], 2)])
+    spans = tracer.spans()
+    ticks = tracer.spans("batcher.tick")
+    assert len(ticks) == b.ticks
+    by_id = {e["id"]: e for e in spans}
+    for e in spans:
+        if e["name"].startswith("tick."):
+            assert by_id[e["parent"]]["name"] == "batcher.tick"
+            parent = by_id[e["parent"]]
+            assert parent["ts"] <= e["ts"] and \
+                e["ts"] + e["dur"] <= parent["ts"] + parent["dur"]
+        if e["name"].startswith("admit."):
+            assert by_id[e["parent"]]["name"] == "batcher.admit"
+            assert e["rid"] == by_id[e["parent"]]["rid"]
+    kids = {e["name"] for e in spans if e["parent"] == ticks[0]["id"]}
+    assert kids == {"tick.launch", "tick.wait", "tick.finish", "tick.scrub"}
+    assert sorted(e["rid"] for e in tracer.spans("batcher.admit")) == [0, 1]
+    reqs = {e["rid"]: e for e in tracer.spans("request")}
+    assert set(reqs) == {0, 1}
+    res = b.results[0].timeline
+    assert reqs[0]["ts"] == int(res.start * 1e9)
+    assert reqs[0]["ts"] + reqs[0]["dur"] == int(res.marks[-1][0] * 1e9)
+    # two counter tracks, one sample per admission and per pool scrub
+    counters = [e for e in tracer.events if e["ph"] == "C"]
+    track = lambda n: [e["args"][n] for e in counters if e["name"] == n]
+    assert {e["name"] for e in counters} == {"batcher.admissions",
+                                             "batcher.scrubs"}
+    assert track("batcher.admissions") == [1.0, 2.0]
+    assert track("batcher.scrubs") == list(
+        map(float, range(1, len(b.scrub_ticks) + 1)))
+    assert len(b.scrub_ticks) == b.ticks
+
+
+def test_tick_single_transfer_contract_with_the_recorder_on(setup):
+    """The transfer contract with an enabled recorder of the batcher's
+    own, spans checked to have been recorded inside the guarded region."""
+    from repro.obs import Tracer
+    cfg, key, params, prompts = setup
+    tracer = Tracer()
+    b = ContinuousBatcher(cfg, parse_scheme("hsiao-wb"), SPEC,
+                          scrub_every=2, tracer=tracer)
+    b.prepare(params, key=key, fault=TransientBitFlips(P_BIT))
+    b.run([Request(99, prompts[8], 3)])       # warmup: compile everything
+    n0, ticks0 = len(tracer.spans("batcher.tick")), b.ticks
+    for r in [Request(0, prompts[8], 6), Request(1, prompts[4], 2)]:
+        b.submit(r)
+    completion_ticks = 0
+    with count_host_transfers() as ledger:
+        b.admit()
+        while b.active or b.queue:
+            if b.tick():
+                completion_ticks += 1
+            b.admit()
+    assert completion_ticks > 0
+    assert ledger.syncs == completion_ticks, ledger.sites
+    assert len(tracer.spans("batcher.tick")) - n0 == b.ticks - ticks0 > 0
+
+
 def test_slot_steps_beat_sequential_2x(setup):
     """On a skewed short/long trace the scheduler recycles the short
     requests' slots while the long ones run; whole-batch serving pads

@@ -143,3 +143,35 @@ def test_serve_reads_nothing_it_donated(monkeypatch, mode):
     monkeypatch.setattr(engine_mod, "DONATE_ON_CPU", True)
     out = serve.main(SERVE_ARGS + mode)
     assert int(out["stats"]["ecc_uncorrectable"]) == 0
+
+
+def test_server_trace_carries_the_batchers_spans(tmp_path):
+    """`serve --server --trace/--metrics`: the Chrome trace holds the
+    scheduler's spans and the server record its slowest ticks, split by
+    child span."""
+    import json
+    from repro.launch import serve
+    trace, metrics = tmp_path / "t.json", tmp_path / "m.jsonl"
+    out = serve.main(SERVE_ARGS + [
+        "--server", "--requests", "3", "--slots", "2", "--rate", "1000",
+        "--chunk", "2", "--page-tokens", "4", "--scheme", "hsiao-wb",
+        "--trace", str(trace), "--metrics", str(metrics)])
+    events = json.loads(trace.read_text())["traceEvents"]
+    names = {e["name"] for e in events}
+    assert {"batcher.tick", "tick.launch", "tick.wait", "tick.finish",
+            "batcher.admit", "admit.launch", "admit.wait",
+            "request", "serve"} <= names
+    served = {e["rid"] for e in events if e["name"] == "request"}
+    assert set(range(3)) <= served
+    (rec,) = [json.loads(ln) for ln in metrics.read_text().splitlines()
+              if json.loads(ln)["kind"] == "server"]
+    slow = rec["slowest_ticks"]
+    assert 0 < len(slow) <= 5
+    assert [t["ms"] for t in slow] == sorted((t["ms"] for t in slow),
+                                             reverse=True)
+    for t in slow:
+        assert set(t["children_ms"]) <= {"tick.launch", "tick.wait",
+                                         "tick.finish", "tick.scrub",
+                                         "tick.scrub_fetch"}
+        assert t["ms"] >= sum(t["children_ms"].values())
+    assert out["batcher"].ticks >= len(slow)

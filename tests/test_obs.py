@@ -247,6 +247,128 @@ def test_null_tracer_records_nothing(tmp_path):
     assert NULL_TRACER.events == [] and NULL_TRACER.records == []
 
 
+def test_spans_carry_ids_parents_and_request_ids():
+    tracer = Tracer()
+    with tracer.trace("batcher.admit", rid=7):
+        with tracer.trace("admit.launch", rid=7):
+            pass
+        with tracer.trace("admit.wait", rid=7):
+            pass
+    with tracer.trace("batcher.tick"):
+        pass
+    tracer.add_span("request", 10, 20, rid=7, tokens=3)
+    spans = {e["name"]: e for e in tracer.spans()}
+    admit = spans["batcher.admit"]
+    assert admit["parent"] is None and admit["rid"] == 7
+    assert spans["admit.launch"]["parent"] == admit["id"]
+    assert spans["admit.wait"]["parent"] == admit["id"]
+    assert spans["batcher.tick"]["parent"] is None
+    assert "rid" not in spans["batcher.tick"]
+    ids = [e["id"] for e in tracer.spans()]
+    assert len(set(ids)) == len(ids) == 5
+    req = spans["request"]
+    assert (req["ts"], req["dur"], req["rid"], req["parent"]) == \
+        (10, 10, 7, None)
+    assert req["args"] == {"tokens": 3}
+    # one request's spans share its rid
+    assert {e["name"] for e in tracer.spans() if e.get("rid") == 7} == {
+        "batcher.admit", "admit.launch", "admit.wait", "request"}
+
+
+def test_spans_are_on_the_perf_counter_clock():
+    import time
+    tracer = Tracer()
+    a = time.perf_counter_ns()
+    with tracer.trace("span"):
+        pass
+    b = time.perf_counter_ns()
+    (e,) = tracer.spans()
+    assert isinstance(e["ts"], int) and a <= e["ts"] <= e["ts"] + e["dur"] \
+        <= b
+
+
+def test_the_ring_is_bounded_and_counts_what_it_drops():
+    tracer = Tracer(capacity=4)
+    for i in range(10):
+        tracer.counter("c", i)
+    assert len(tracer.events) == 4 and tracer.dropped == 6
+    assert [e["args"]["c"] for e in tracer.events] == [6.0, 7.0, 8.0, 9.0]
+    for i in range(5):
+        tracer.metrics({"i": i})
+    assert [r["i"] for r in tracer.records] == [1, 2, 3, 4]
+    assert tracer.dropped == 7
+
+
+def test_each_span_enters_a_profiler_annotation(monkeypatch):
+    import contextlib
+
+    from repro.obs import trace as TRACE
+    entered = []
+
+    @contextlib.contextmanager
+    def annotation(name):
+        entered.append(name)
+        yield
+
+    monkeypatch.setattr(TRACE, "TraceAnnotation", annotation)
+    tracer = Tracer()
+    with tracer.trace("batcher.tick"):
+        with tracer.trace("tick.wait"):
+            pass
+    with NULL_TRACER.trace("never"):
+        pass
+    assert entered == ["batcher.tick", "tick.wait"]
+
+
+def test_a_disabled_tracer_records_nothing():
+    tracer = Tracer(enabled=False, capacity=2)
+    with tracer.trace("span", rid=1):
+        tracer.counter("c", 1.0)
+    tracer.add_span("request", 0, 5, rid=1)
+    tracer.instant("i")
+    tracer.metrics({"x": 1})
+    assert tracer.events == [] and tracer.records == []
+    assert tracer.spans() == [] and tracer.slowest() == []
+    assert tracer.dropped == 0
+
+
+def test_slowest_ticks_split_by_child_span():
+    tracer, ms = Tracer(), 10 ** 6
+    for n, (launch, wait) in enumerate([(1, 30), (2, 90), (1, 10)]):
+        base = n * 10 ** 9
+        tracer.add_span("batcher.tick", base, base + (launch + wait + 1) * ms)
+        tick = tracer.spans("batcher.tick")[-1]["id"]
+        tracer.add_span("tick.launch", base, base + launch * ms, parent=tick)
+        tracer.add_span("tick.wait", base + launch * ms,
+                        base + (launch + wait) * ms, parent=tick)
+    top = tracer.slowest("batcher.tick", k=2)
+    assert [t["ms"] for t in top] == [93.0, 32.0]
+    assert top[0]["children_ms"] == {"tick.launch": 2.0, "tick.wait": 90.0}
+    assert top[0]["other_ms"] == pytest.approx(1.0)
+    assert top[1]["start_s"] == 0.0
+
+
+def test_the_process_recorder_is_on_and_bounded():
+    from repro.obs import CAPACITY, RECORDER
+    assert RECORDER.enabled
+    assert RECORDER._events.maxlen == CAPACITY
+
+
+def test_a_file_writing_tracer_keeps_every_event_and_record(tmp_path):
+    from repro.obs import CAPACITY
+    tracer, n = Tracer(), CAPACITY + 5
+    for i in range(n):
+        tracer.counter("c", i)
+        tracer.metrics({"i": i})
+    tracer.write_chrome(str(tmp_path / "t.json"))
+    tracer.write_jsonl(str(tmp_path / "m.jsonl"))
+    events = json.load(open(tmp_path / "t.json"))["traceEvents"]
+    assert [e["args"]["c"] for e in events] == list(map(float, range(n)))
+    lines = (tmp_path / "m.jsonl").read_text().splitlines()
+    assert [json.loads(x)["i"] for x in lines] == list(range(n))
+    assert tracer.dropped == 0
+
+
 # --------------------------------------------------------------------------
 # latency tails
 # --------------------------------------------------------------------------
@@ -509,3 +631,42 @@ def test_scrub_metrics_from_fetched():
     assert rec.corrected == 3 and rec.uncorrectable == 1
     assert rec.injected == 7
     assert rec.vote_disagreements == 4 + 3      # final + summed series
+
+
+def test_phases_survive_a_cached_unscoped_program(tmp_path):
+    """The persistent cache leaves metadata out of its key by default: a
+    program cached without its scopes must not come back for the scoped
+    one, or the phase map would be empty."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from repro.obs import Phased
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_enable_compilation_cache")
+    was = {n: getattr(jax.config, n) for n in names}
+    for n, v in zip(names, (str(tmp_path), 0, True)):
+        jax.config.update(n, v)
+    cc.reset_cache()
+    try:
+        def plain():
+            def tick(x):
+                return jnp.sin(x) * 2 + 1
+            return tick
+
+        def scoped():
+            def tick(x):
+                with jax.named_scope("gather"):
+                    y = jnp.sin(x)
+                with jax.named_scope("step"):
+                    return y * 2 + 1
+            return tick
+
+        x = jnp.arange(8.0)
+        jax.jit(plain()).lower(x).compile()          # cached, no scopes
+        prog = Phased(jax.jit(scoped()), ("gather", "step"))
+        np.testing.assert_array_equal(prog(x), jnp.sin(x) * 2 + 1)
+        assert prog.program == "tick"
+        assert {"gather", "step"} & {p for p, *_ in prog.phase_map.values()}
+    finally:
+        for n, v in was.items():
+            jax.config.update(n, v)
+        cc.reset_cache()
