@@ -7,7 +7,11 @@ layer that keeps the batch full *without giving up any of the reliability
 invariants*:
 
 * **paged KV pool** (`PagedKVPool`) — KV state for all in-flight requests
-  lives in fixed-size pages of one pool array per k/v.  The pool packs
+  lives in fixed-size pages of one pool array per k/v, each page stored
+  lane-dense as (L, page_tokens, KV·hd): the kv heads and head_dim merge
+  into one trailing axis, so a head_dim off the TPU's 128 lanes (phi3's
+  96) cannot push the page axis into the lanes, where every page access
+  sweeps the whole pool.  The pool packs
   into the block-aligned uint32 arena (core/arena.py) — every page spans
   a whole number of ECC blocks — so the *same* fused diagonal-parity
   launches that protect the weights cover the KV state: `scrub()` is one
@@ -192,12 +196,17 @@ class _Active:
 class PagedKVPool:
     """Page-granular KV storage for one `BatchSpec`, ECC-protectable.
 
-    Layout: k/v arrays of shape (pool_pages + 1, L, page_tokens, KV, hd)
+    Layout: k/v arrays of shape (pool_pages + 1, L, page_tokens, KV·hd)
     in the model compute dtype — page 0 is reserved scratch — with a
     leading 3-copy axis when `copies` (TMR/Compose store three
     independent cache states, one per weight copy; they are never voted
     or parity-shared across copies — each copy's KV is legitimate state
-    of *that* copy's generation).
+    of *that* copy's generation).  The trailing axis holds the kv heads
+    and head_dim merged, in the same row-major order as (KV, hd): the
+    packed words, parity rows and word indices are those of a
+    (…, KV, hd) pool, while the TPU tiles the merged axis into lanes
+    whatever head_dim is.  Only the dense view the decode step reads
+    splits it (`ContinuousBatcher._gather`).
 
     With `ecc`, the whole pool (all copies) packs into ONE block-aligned
     uint32 arena — the word code is block-local and every page spans a
@@ -211,7 +220,7 @@ class PagedKVPool:
                  copies: bool, ecc: Optional[ArenaEcc] = None):
         self.cfg, self.spec, self.ecc, self.copies = cfg, spec, ecc, copies
         L, KV, hd = cfg.n_layers, cfg.n_kv, cfg.head_dim
-        self.page_shape = (L, spec.page_tokens, KV, hd)
+        self.page_shape = (L, spec.page_tokens, KV * hd)
         if ecc is not None:
             pw = arena.words_for(self.page_shape, cfg.cdtype)
             if pw % arena.BLOCK:
@@ -423,24 +432,27 @@ class ContinuousBatcher:
     # -- program builders -----------------------------------------------------
 
     def _gather(self, pool, table):
-        """(pool_pages+1, L, P, KV, hd)[table (S, MP)] ->
+        """(pool_pages+1, L, P, KV·hd)[table (S, MP)] ->
         (L, S, S_cap, KV, hd): every slot's page-table view as a dense
-        cache.  Pure value-copy — page identity cannot affect tokens."""
-        S, MP = self.spec.slots, self.spec.max_pages
-        g = pool[table]                                # (S, MP, L, P, KV, hd)
-        g = jnp.transpose(g, (2, 0, 1, 3, 4, 5))       # (L, S, MP, P, ...)
+        cache, the merged head axis split only here.  Pure value-copy —
+        page identity cannot affect tokens."""
+        S, MP = table.shape
+        g = pool[table]                                # (S, MP, L, P, KV·hd)
+        g = jnp.moveaxis(g, 2, 0)                      # (L, S, MP, P, KV·hd)
         return g.reshape(g.shape[0], S, MP * self.spec.page_tokens,
-                         *g.shape[4:])
+                         self.cfg.n_kv, self.cfg.head_dim)
 
     def _scatter(self, pool, table, cache):
-        """Inverse of `_gather`: write the mutated views back.  Scratch
-        page 0 appears once per unreserved table entry; the duplicate
-        writes race, but nothing ever reads page 0 through a validity
-        mask, so the winner is immaterial."""
-        S, MP, P = self.spec.slots, self.spec.max_pages, self.spec.page_tokens
+        """Inverse of `_gather`: merge the head axes of the mutated views
+        (L, S, S_cap, KV, hd) and write them back as pages — the tick's
+        whole table, or one slot's row at admission.  Scratch page 0
+        appears once per unreserved table entry; the duplicate writes
+        race, but nothing ever reads page 0 through a validity mask, so
+        the winner is immaterial."""
+        S, MP = table.shape
         L = cache.shape[0]
-        c = cache.reshape(L, S, MP, P, *cache.shape[3:])
-        c = jnp.transpose(c, (1, 2, 0, 3, 4, 5))       # (S, MP, L, P, ...)
+        c = cache.reshape(L, S, MP, self.spec.page_tokens, -1)
+        c = jnp.moveaxis(c, 0, 2)                      # (S, MP, L, P, KV·hd)
         return pool.at[table].set(c.astype(pool.dtype))
 
     def _refresh_parity(self, pk, pv, parity, pages=None):
@@ -604,15 +616,11 @@ class ContinuousBatcher:
         if plen in self._admit_fns:
             return self._admit_fns[plen]
         prefill = make_prefill_step(self.cfg, cache_len=self.spec.cache_tokens)
-        MP, P = self.spec.max_pages, self.spec.page_tokens
         copy, serial = self._copy, self._serial
 
         def place(pool, table_row, cache_kv):
-            # (L, 1, S_cap, KV, hd) -> (MP, L, P, KV, hd) at table_row
-            L = cache_kv.shape[0]
-            c = cache_kv[:, 0].reshape(L, MP, P, *cache_kv.shape[3:])
-            c = jnp.transpose(c, (1, 0, 2, 3, 4))
-            return pool.at[table_row].set(c.astype(pool.dtype))
+            # (L, 1, S_cap, KV, hd) -> pages (MP, L, P, KV·hd) at table_row
+            return self._scatter(pool, table_row[None], cache_kv)
 
         def admit(store, tok, out, pk, pv, pos, parity, tokens, table_row,
                   slot):

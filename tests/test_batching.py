@@ -17,8 +17,10 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.core import arena
 from repro.faults import TransientBitFlips
 from repro.launch import (BatchSpec, ContinuousBatcher, GenerationEngine,
                           PagedKVPool, Request, fetch_telemetry,
@@ -376,6 +378,141 @@ def test_page_zero_is_scratch(setup):
     assert (b.table[0] == 0).sum() >= 1          # unreserved entries
     assert (b.table[1] == 0).all()               # empty slot
     assert all(p >= 1 for p in b._slots[0].pages)
+
+
+# -- lane-dense pool pages: (..., KV*hd) is (..., KV, hd) merged ------------
+
+def _split_heads(cfg, x):
+    return x.reshape(x.shape[:-1] + (cfg.n_kv, cfg.head_dim))
+
+
+def _split_pool(cfg, pool):
+    """Hold `pool`'s pages as (L, P, KV, hd), the layout before the merge."""
+    pool.k, pool.v = _split_heads(cfg, pool.k), _split_heads(cfg, pool.v)
+    pool.page_shape = pool.k.shape[-4:]
+    pool.arena_spec = arena.arena_spec({"k": pool.k, "v": pool.v})
+    return pool
+
+
+class _UnmergedBatcher(ContinuousBatcher):
+    """The same scheduler over a pool that keeps pages as (L, P, KV, hd):
+    the reference the merged trailing axis must match word for word."""
+
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        _split_pool(self.cfg, self.pool)
+
+    def _gather(self, pool, table):
+        S, MP = table.shape
+        g = jnp.transpose(pool[table], (2, 0, 1, 3, 4, 5))
+        return g.reshape(g.shape[0], S, MP * self.spec.page_tokens,
+                         *g.shape[4:])
+
+    def _scatter(self, pool, table, cache):
+        S, MP = table.shape
+        c = cache.reshape(cache.shape[0], S, MP, self.spec.page_tokens,
+                          *cache.shape[3:])
+        return pool.at[table].set(
+            jnp.transpose(c, (1, 2, 0, 3, 4, 5)).astype(pool.dtype))
+
+
+def _random_pool(cfg, seed):
+    shape = (SPEC.pool_pages + 1, cfg.n_layers, SPEC.page_tokens,
+             cfg.n_kv * cfg.head_dim)
+    return jax.random.normal(jax.random.PRNGKey(seed), shape).astype(
+        cfg.cdtype)
+
+
+def test_pool_pages_are_lane_dense_and_pack_in_head_order():
+    """The pool stores (L, P, KV*hd) pages, KV*hd off a multiple of 128
+    here as on phi3, and packs into the very words of the same values
+    held as (L, P, KV, hd)."""
+    cfg = _cfg()
+    assert (cfg.n_kv * cfg.head_dim) % 128
+    pool = PagedKVPool(cfg, SPEC, copies=False, ecc=parse_scheme("hsiao"))
+    assert pool.page_shape == (cfg.n_layers, SPEC.page_tokens,
+                               cfg.n_kv * cfg.head_dim)
+    k, v = _random_pool(cfg, 1), _random_pool(cfg, 2)
+    merged = arena.pack({"k": k, "v": v})[0]
+    split = arena.pack({"k": _split_heads(cfg, k),
+                        "v": _split_heads(cfg, v)})[0]
+    np.testing.assert_array_equal(np.asarray(merged), np.asarray(split))
+    assert merged.shape[0] == pool.arena_spec.n_words
+
+
+@pytest.mark.parametrize("word,bit", [(0, 3), (5, 17), (37, 30), (63, 8)])
+def test_corrupt_page_flips_the_same_element_as_split_heads(word, bit):
+    """`corrupt_page(page, word=w, bit=b)` flips the element with the same
+    (layer, token, head, dim) in the merged pool as in a (..., KV, hd)
+    one: the word index means what it meant before the merge."""
+    cfg = _cfg()
+    merged = PagedKVPool(cfg, SPEC, copies=False)
+    split = _split_pool(cfg, PagedKVPool(cfg, SPEC, copies=False))
+    for pool in (merged, split):
+        pool.corrupt_page(3, word=word, bit=bit)
+    bits = lambda x: np.asarray(x).view(np.uint16)   # subnormals too
+    (pg, l, t, j), = np.argwhere(bits(merged.k))
+    (pg2, l2, t2, h2, d2), = np.argwhere(bits(split.k))
+    assert (pg, l, t, j // cfg.head_dim, j % cfg.head_dim) == \
+        (pg2, l2, t2, h2, d2)
+    assert pg == 3
+
+
+def test_gather_and_scatter_match_the_split_head_reference(setup):
+    """The dense view `_gather` forms from merged pages equals the view
+    of the same values stored as (..., KV, hd), and `_scatter` writes
+    back exactly the pages that reference writes."""
+    cfg = setup[0]
+    b = ContinuousBatcher(cfg, None, SPEC)
+    ref = _UnmergedBatcher(cfg, None, SPEC)
+    pool = _random_pool(cfg, 3)
+    rng = np.random.default_rng(0)
+    table = jnp.asarray(rng.integers(0, SPEC.pool_pages + 1,
+                                     (SPEC.slots, SPEC.max_pages)), jnp.int32)
+    view = b._gather(pool, table)
+    assert view.shape == (cfg.n_layers, SPEC.slots, SPEC.cache_tokens,
+                          cfg.n_kv, cfg.head_dim)
+    np.testing.assert_array_equal(
+        np.asarray(view), np.asarray(ref._gather(_split_heads(cfg, pool),
+                                                 table)))
+    # distinct pages, so no write races: the scatters must agree exactly
+    table = jnp.asarray(rng.permutation(SPEC.pool_pages + 1)[
+        :SPEC.slots * SPEC.max_pages].reshape(SPEC.slots, SPEC.max_pages),
+        jnp.int32)
+    new = _random_pool(cfg, 4)
+    cache = b._gather(new, table)
+    np.testing.assert_array_equal(
+        np.asarray(_split_heads(cfg, b._scatter(pool, table, cache))),
+        np.asarray(ref._scatter(_split_heads(cfg, pool), table, cache)))
+
+
+@pytest.mark.parametrize("name", ["off", "hsiao-wb", "ecc+tmr-semi"])
+def test_served_tokens_and_parity_match_an_unmerged_pool(setup, name):
+    """A live batch served from merged pages gives the tokens, the pool
+    words and the parity rows of the same batch served from a pool that
+    never merges its head axes — faults, write-back repair and a scrub
+    included."""
+    cfg, key, params, prompts = setup
+    reqs = [Request(0, prompts[8], 6), Request(1, prompts[4], 2),
+            Request(9, prompts[8], 5, arrival_s=0.1)]
+
+    def serve(cls):
+        b = cls(cfg, parse_scheme(name), SPEC, scrub_every=2)
+        b.prepare(params, key=key, fault=TransientBitFlips(P_BIT))
+        if b.ecc is not None:
+            b.pool.corrupt_page(1, word=7, bit=5)
+        toks = [r.tokens for r in b.run(reqs)]
+        words = arena.pack({"k": b.pool.k, "v": b.pool.v})[0]
+        return toks, np.asarray(words), b.pool.parity
+
+    toks, words, parity = serve(ContinuousBatcher)
+    toks_ref, words_ref, parity_ref = serve(_UnmergedBatcher)
+    for t, r in zip(toks, toks_ref):
+        np.testing.assert_array_equal(t, r)
+    np.testing.assert_array_equal(words, words_ref)
+    if parity is not None:
+        np.testing.assert_array_equal(np.asarray(parity),
+                                      np.asarray(parity_ref))
 
 
 # -- engine chunk-compile cache (satellite) ----------------------------------

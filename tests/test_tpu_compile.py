@@ -14,11 +14,17 @@ The engine's TMR programs on a copy-folded 3x1 mesh of the described
 chip, and the in-scan vote programs, whose Mosaic vote XLA cannot
 partition (it has to run inside `shard_map`).
 
+The serving scheduler's tick and admission programs compile here at the
+decode cells' shapes, and their paged KV pool must keep its page axis out
+of the lanes: a pool laid out page-minor makes every page access sweep
+the whole pool.
+
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +39,7 @@ from repro.kernels.diag_parity import encode_parity, scrub as diag_scrub
 from repro.kernels.hsiao_secded import encode_hsiao, scrub as hsiao_scrub
 from repro.kernels.inject_scrub import inject_scrub
 from repro.kernels.tmr_vote import vote
-from repro.launch.batching import BatchSpec
+from repro.launch.batching import BatchSpec, ContinuousBatcher
 from repro.launch.engine import GenerationEngine
 from repro.models.params import Spec
 from repro.models.transformer import model_specs
@@ -246,3 +252,77 @@ def test_in_scan_vote_programs_compile_for_v5e_3x1(devices, compiled_vote):
                                         mesh, PartitionSpec("copy")))
         assert _mosaic(jax.jit(lambda s: eng._voter()(s[0], s[1], s[2]))
                        .lower(seq3).compile())
+
+
+#: the phi3 decode cells' serving spec: 8 slots, 16-token pages,
+#: prompt 128, up to 256 generated tokens, 8 decode steps a tick
+DECODE_SPEC = BatchSpec(slots=8, page_tokens=16, chunk=8,
+                        prompt_buckets=(128,), gen_cap=256)
+_ARRAY = re.compile(r"\b[a-z]+\d*\[([\d,]+)\]\{([\d,]+)")
+
+
+def _abstract_batcher(scheme, one_chip):
+    """phi3-mini-3.8b's `ContinuousBatcher` at published widths, built
+    under `eval_shape`, and the arguments of its programs: the store, the
+    slot state, the pool and its parity, all shapes with the chip's
+    sharding.  Nothing is allocated."""
+    cfg = get_config("phi3-mini-3.8b")
+    box = {}
+
+    def build():
+        b = box["b"] = ContinuousBatcher(cfg, parse_scheme(scheme),
+                                         DECODE_SPEC)
+        return {"tok": b._tok, "out": b._out, "pos": b._pos,
+                "k": b.pool.k, "v": b.pool.v, "parity": b.pool.parity}
+
+    st = _abstract(jax.eval_shape(build), one_chip)
+    b = box["b"]
+    b._tok, b._out, b._pos = st["tok"], st["out"], st["pos"]
+    b.pool.k, b.pool.v, b.pool.parity = st["k"], st["v"], st["parity"]
+    store = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.resolved_dtype(cfg.cdtype),
+                                       sharding=one_chip),
+        model_specs(cfg), is_leaf=lambda x: isinstance(x, Spec))
+    return b, (store, b._tok, b._out, b.pool.k, b.pool.v, b._pos,
+               b.pool.parity)
+
+
+def _page_minor_arrays(hlo: str, pages: int):
+    """Every array of `pages` pages in `hlo` whose layout puts the page
+    axis minor, as (dims, minor-to-major); and how many such arrays it
+    holds in all."""
+    bad, n = [], 0
+    for dims, layout in _ARRAY.findall(hlo):
+        dims = tuple(map(int, dims.split(",")))
+        if len(dims) < 4 or pages not in dims[:2]:
+            continue
+        n += 1
+        minor = int(layout.split(",")[0])
+        if dims[minor] == pages:
+            bad.append((dims, layout))
+    return bad, n
+
+
+@pytest.mark.parametrize("program", ["tick", "admit"])
+@pytest.mark.parametrize("scheme", ["off", "hsiao-wb"])
+def test_pool_pages_stay_out_of_the_lanes_on_v5e(one_chip, scheme,
+                                                 program):
+    """The tick and the admission of the phi3 decode cells keep the page
+    axis of every pool-shaped array out of the minor (lane) dimension:
+    head_dim 96 is off the 128 lanes, and a pool stored (..., KV, hd)
+    was laid out with its 201 pages minor, each page read or written by
+    a pass over the whole pool."""
+    b, state = _abstract_batcher(scheme, one_chip)
+    spec = DECODE_SPEC
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    if program == "tick":
+        lowered = b._tick_program().jitted.lower(
+            *state, i32(spec.slots, spec.max_pages), i32(spec.slots))
+    else:
+        lowered = b._admit_program(spec.max_prompt).jitted.lower(
+            *state, i32(1, spec.max_prompt), i32(spec.max_pages), i32())
+    bad, n = _page_minor_arrays(lowered.compile().as_text(),
+                                spec.pool_pages + 1)
+    assert n, "no pool-shaped array in the compiled program"
+    assert not bad, f"pool arrays with the page axis minor: {bad[:3]}"
